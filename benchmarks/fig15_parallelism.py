@@ -1,7 +1,9 @@
 """Paper Fig. 15 — per-device memory under DP / TP / PP, plus the
 exposed-cross-pod-comm sweep for the overlapped gradient sync.
 
-Runs in subprocesses with 8 virtual devices (flags must precede jax import).
+Runs in subprocesses with 8 virtual CPU devices (flags must precede jax
+import); ``JAX_PLATFORMS=cpu`` keeps them off a chip the parent holds, and
+each result records the platform it ran on.
 
 ``main`` part 1 (memory): for one transformer config, computes the exact
 per-device parameter + optimizer-state bytes under
@@ -50,11 +52,12 @@ from repro.models import param_axes
 from repro.train import OptConfig
 from repro.train.trainer import abstract_state, tree_shardings
 from repro.launch.dryrun import _sharded_bytes
+from repro.launch.mesh import make_mesh
 
 cfg = C.get("paper-gpt2")
 opt_cfg = OptConfig()
 p_shapes, o_shapes = abstract_state(cfg, opt_cfg)
-out = {}
+out = {"platform": jax.devices()[0].platform}
 
 def bytes_per_device(mesh, rules):
     set_mesh(mesh, rules)
@@ -62,13 +65,13 @@ def bytes_per_device(mesh, rules):
     return _sharded_bytes(p_shapes, p_sh)
 
 # DP: 8-way data, no model sharding -> params replicated
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+mesh = make_mesh((8, 1), ("data", "model"))
 rules = ShardingRules({**DEFAULT_RULES, "p_embed": None, "p_vocab": None,
                        "p_heads": None, "p_ff": None, "p_kv_heads": None})
 out["DP"] = [bytes_per_device(mesh, rules)] * 8
 
 # TP: 8-way model sharding (ZeRO off to isolate TP)
-mesh = jax.make_mesh((1, 8), ("data", "model"))
+mesh = make_mesh((1, 8), ("data", "model"))
 rules = ShardingRules({**DEFAULT_RULES, "p_embed": None})
 out["TP"] = [bytes_per_device(mesh, rules)] * 8
 
@@ -103,13 +106,14 @@ from repro.dist.sharding import set_mesh
 from repro.dist.collectives import GROUP, make_pod_sync
 from repro.train import OptConfig, trainer
 from repro.core.hlo import analyze_text
+from repro.launch.mesh import make_mesh
 
 cfg = C.reduced(C.get("paper-gpt2"))
 opt_cfg = OptConfig()
-out = {}
+out = {"platform": jax.devices()[0].platform}
 
 # ---- blocking vs bucketed-overlap train step on a pod x data x model mesh
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 set_mesh(mesh)
 p_sh, o_sh, p_shapes, o_shapes = trainer.train_shardings(mesh, cfg, opt_cfg)
 specs = {"inputs": jax.ShapeDtypeStruct((8, 32), jnp.int32),
@@ -150,7 +154,7 @@ n_el = 64 * 64 + 128
 for npods, mesh_spec in [(2, ((2, 4), ("pod", "data"))),
                          (4, ((4, 2), ("pod", "data"))),
                          (8, ((8,), ("pod",)))]:
-    m = jax.make_mesh(*mesh_spec)
+    m = make_mesh(*mesh_spec)
     sync = make_pod_sync(m, compressed=True)
     text = jax.jit(sync).lower(tree).compile().as_text()
     stats = analyze_text(text)
@@ -168,6 +172,7 @@ print(json.dumps(out))
 def exposed_comm() -> list:
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(repo, "src")
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(_EXPOSED_SUB)],
@@ -230,6 +235,7 @@ def exposed_comm() -> list:
 def memory_modes() -> list:
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(repo, "src")
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(_SUB)],

@@ -89,7 +89,8 @@ _KNOWN_OPCODES = {
     "cholesky", "triangular-solve", "fft", "clz", "popcnt", "is-finite",
     "real", "imag", "complex", "stochastic-convert", "infeed", "outfeed",
     "send", "recv", "send-done", "recv-done", "async-start",
-    "async-update", "async-done", "add-dependency",
+    "async-update", "async-done", "add-dependency", "slice-start",
+    "slice-done",
 } | _FREE_OPCODES | _ARITH_OPCODES
 
 
@@ -353,12 +354,11 @@ def _is_collective_done(opcode: str) -> bool:
     return opcode.endswith("-done") and opcode[:-5] in COLLECTIVE_OPCODES
 
 
-#: hardware model used for overlap credit when the caller supplies none
-#: (kept in sync with repro.core.tools.roofline.V5E, imported lazily to
-#: avoid a tools→hlo→tools import cycle at module load)
 def _default_hw() -> dict:
-    from repro.core.tools.roofline import V5E
-    return V5E
+    """Peaks of the analytic target chip, used for overlap credit when the
+    caller supplies none (imported lazily: tools→hlo→tools cycle)."""
+    from repro.core.tools.roofline import ANALYTIC_TARGET, peaks
+    return peaks(ANALYTIC_TARGET)
 
 
 def collective_wire_bytes(opcode: str, op_bytes: float, out_bytes: float,
@@ -445,12 +445,56 @@ def _dot_flops(comp: Computation, ins: Instruction) -> float:
     return 2.0 * out_numel * k
 
 
+def _window_fields(attrs: str) -> dict:
+    """``window={size=1x12 pad=0_0x11_11 ...}`` → {field: [per-dim str]}."""
+    m = re.search(r"window=\{([^}]*)\}", attrs)
+    if not m:
+        return {}
+    return {k: v.split("x") for k, v in
+            (f.split("=", 1) for f in m.group(1).split() if "=" in f)}
+
+
+def _conv_taps(lhs_len: int, out_len: int, size: int, stride: int,
+               pad_lo: int, lhs_dilate: int) -> float:
+    """Mean kernel taps per output position that land on a real input
+    element (not padding, not a dilation hole) along one spatial dim."""
+    last = (lhs_len - 1) * lhs_dilate
+    hits = sum(1 for o in range(out_len) for k in range(size)
+               if 0 <= o * stride + k - pad_lo <= last
+               and (o * stride + k - pad_lo) % lhs_dilate == 0)
+    return hits / max(out_len, 1)
+
+
 def _conv_flops(comp: Computation, ins: Instruction) -> float:
+    """2 × output elements × multiply-adds per output.  The TPU compiler
+    lowers matmuls to convolutions that carry batch and contraction dims as
+    padded, dilated windows (``window={size=1x12 pad=0_0x11_11}``,
+    ``lhs_dilate=8x12``), so the multiply-adds are the kernel's input
+    features times the taps that reach a real input element."""
+    out_dims = _first_shape_dims(ins.shape)
     out_numel = shape_numel(ins.shape)
+    lhs_dims = _first_shape_dims(comp.shape_of(ins.operands[0])
+                                 if ins.operands else "")
     rhs_shape = comp.shape_of(ins.operands[1]) if len(ins.operands) > 1 else ""
-    k = max(1, shape_numel(rhs_shape) // max(1, _first_shape_dims(rhs_shape)[-1]
-                                             if _first_shape_dims(rhs_shape) else 1))
-    return 2.0 * out_numel * k
+    rhs_dims = _first_shape_dims(rhs_shape)
+    m = re.search(r"dim_labels=(\w+)_(\w+)->(\w+)", ins.attrs)
+    if not (m and lhs_dims and rhs_dims and out_dims):
+        k = shape_numel(rhs_shape) // max(1, rhs_dims[-1] if rhs_dims else 1)
+        return 2.0 * out_numel * max(1, k)
+    lhs_l, rhs_l, out_l = m.groups()
+    win = _window_fields(ins.attrs)
+
+    def field(name, i, default):
+        vals = win.get(name)
+        return vals[i] if vals and i < len(vals) else default
+    macs = float(rhs_dims[rhs_l.index("i")])
+    for i, d in enumerate(sorted(c for c in rhs_l if c.isdigit())):
+        macs *= _conv_taps(
+            lhs_dims[lhs_l.index(d)], out_dims[out_l.index(d)],
+            int(field("size", i, 1)), int(field("stride", i, 1)),
+            int(field("pad", i, "0_0").split("_")[0]),
+            int(field("lhs_dilate", i, 1)))
+    return 2.0 * out_numel * macs
 
 
 def _computation_flops(module: HloModule, comp: Computation, memo: dict) -> float:

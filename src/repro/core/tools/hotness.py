@@ -30,13 +30,22 @@ class HotnessTool(PastaTool):
         self.hot_frac = hot_frac
         self.hot = np.zeros((n_tbins, n_blocks), dtype=np.int64)
 
-    def on_trace_buffer(self, ev):
-        h = ev.attrs.get("hotness_map")
-        if h is None:
-            return
+    def _add(self, h) -> None:
+        """Accumulate one map; the matrix grows to the processor's
+        [time-bin × block] shape when that is larger than the tool's."""
         h = np.asarray(h)
         tb, nb = h.shape
+        if tb > self.hot.shape[0] or nb > self.hot.shape[1]:
+            grown = np.zeros((max(tb, self.hot.shape[0]),
+                              max(nb, self.hot.shape[1])), dtype=np.int64)
+            grown[:self.hot.shape[0], :self.hot.shape[1]] = self.hot
+            self.hot = grown
         self.hot[:tb, :nb] += h
+
+    def on_trace_buffer(self, ev):
+        h = ev.attrs.get("hotness_map")
+        if h is not None:
+            self._add(h)
 
     def on_batch(self, batch):
         """Sum the per-buffer device aggregates straight off the attrs side
@@ -44,11 +53,8 @@ class HotnessTool(PastaTool):
         for i in batch.rows(EventKind.TRACE_BUFFER):
             a = batch.attrs_at(int(i))
             h = None if a is None else a.get("hotness_map")
-            if h is None:
-                continue
-            h = np.asarray(h)
-            tb, nb = h.shape
-            self.hot[:tb, :nb] += h
+            if h is not None:
+                self._add(h)
 
     def classify(self, hot_frac: float = 0.5):
         """Split blocks into persistent-hot vs bursty vs cold."""

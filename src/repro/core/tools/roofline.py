@@ -10,22 +10,44 @@ peaks — algebraically identical to global/(chips×peak)):
     memory     = HLO_bytes_per_chip    / HBM_bw
     collective = coll_bytes_per_chip   / link_bw
 
-Hardware constants: TPU v5e.
+Hardware constants: :data:`PEAKS`, keyed by ``jax.Device.device_kind``.
 """
 
 from __future__ import annotations
 
 import dataclasses
 
-V5E = {
-    "peak_flops": 197e12,      # bf16 FLOP/s per chip
-    "hbm_bw": 819e9,           # bytes/s per chip
-    "ici_bw": 50e9,            # bytes/s per ICI link (intra-pod)
-    "dci_bw": 12.5e9,          # bytes/s inter-pod (DCI — the slow link the
-                               # compressed/overlapped pod sync targets)
-    "ici_latency": 1e-6,       # per-collective launch/sync latency (alpha)
-    "hbm_bytes": 16 * 1024**3, # capacity per chip
+#: Per-chip peaks keyed by ``jax.Device.device_kind``.  Source: Google Cloud
+#: documentation, "TPU v5e" — 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at
+#: 819 GB/s, 1,600 Gbit/s inter-chip interconnect (``ici_bw`` is that split
+#: over a v5e chip's four ICI links).  ``dci_bw`` and ``ici_latency`` are
+#: modelled, not published.
+PEAKS = {
+    "TPU v5 lite": {
+        "peak_flops": 197e12,      # bf16 FLOP/s per chip
+        "hbm_bw": 819e9,           # bytes/s per chip
+        "ici_bw": 50e9,            # bytes/s per ICI link (intra-pod)
+        "dci_bw": 12.5e9,          # bytes/s inter-pod (DCI — the slow link
+                                   # the compressed/overlapped pod sync
+                                   # targets)
+        "ici_latency": 1e-6,       # per-collective launch/sync latency
+        "hbm_bytes": 16e9,         # capacity per chip
+    },
 }
+
+#: the chip the analytic models (dry-run, lint, HLO overlap) target
+ANALYTIC_TARGET = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """Peaks of ``device_kind``; a chip missing from :data:`PEAKS` is an
+    error, never a default."""
+    try:
+        return dict(PEAKS[device_kind])
+    except KeyError:
+        raise KeyError(f"no published peaks for device_kind "
+                       f"{device_kind!r}; add them to PEAKS with their "
+                       f"source") from None
 
 
 @dataclasses.dataclass
@@ -33,6 +55,7 @@ class Roofline:
     compute_s: float
     memory_s: float
     collective_s: float
+    peak_flops: float
     model_flops_per_chip: float = 0.0
     hlo_flops_per_chip: float = 0.0
 
@@ -53,7 +76,7 @@ class Roofline:
         useful-FLOPs/chip / peak / step_time."""
         if self.step_time_s <= 0:
             return 0.0
-        return (self.model_flops_per_chip / V5E["peak_flops"]) / self.step_time_s
+        return (self.model_flops_per_chip / self.peak_flops) / self.step_time_s
 
     @property
     def useful_flops_ratio(self) -> float:
@@ -74,12 +97,13 @@ class Roofline:
 
 
 def roofline(flops_per_chip: float, hbm_bytes_per_chip: float,
-             coll_bytes_per_chip: float, model_flops_per_chip: float = 0.0,
-             hw: dict = V5E) -> Roofline:
+             coll_bytes_per_chip: float, hw: dict,
+             model_flops_per_chip: float = 0.0) -> Roofline:
     return Roofline(
         compute_s=flops_per_chip / hw["peak_flops"],
         memory_s=hbm_bytes_per_chip / hw["hbm_bw"],
         collective_s=coll_bytes_per_chip / hw["ici_bw"],
+        peak_flops=hw["peak_flops"],
         model_flops_per_chip=model_flops_per_chip,
         hlo_flops_per_chip=flops_per_chip,
     )
@@ -115,10 +139,10 @@ class RooflineTool(PastaTool):
     EVENTS = (EventKind.KERNEL_LAUNCH, EventKind.COLLECTIVE,
               EventKind.COMPILE)
 
-    def __init__(self, hw: dict = V5E, model_flops_per_chip: float = 0.0,
-                 **knobs):
+    def __init__(self, device_kind: str = ANALYTIC_TARGET,
+                 model_flops_per_chip: float = 0.0, **knobs):
         super().__init__(**knobs)
-        self.hw = dict(hw)
+        self.hw = peaks(device_kind)
         self.model_flops_per_chip = model_flops_per_chip
         self.flops = 0.0
         self.hbm_bytes = 0.0
@@ -157,9 +181,8 @@ class RooflineTool(PastaTool):
                 self.flops += float(ca.get("flops", 0.0))
 
     def finalize(self) -> dict:
-        rl = roofline(self.flops, self.hbm_bytes, self.coll_bytes,
-                      model_flops_per_chip=self.model_flops_per_chip,
-                      hw=self.hw)
+        rl = roofline(self.flops, self.hbm_bytes, self.coll_bytes, self.hw,
+                      model_flops_per_chip=self.model_flops_per_chip)
         out = rl.as_dict()
         out.update(kernel_invocations=self.kernel_invocations,
                    hbm_bytes=self.hbm_bytes, coll_bytes=self.coll_bytes,

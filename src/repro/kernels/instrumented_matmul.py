@@ -21,6 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BM = 128
 BN = 128
@@ -36,14 +37,15 @@ def _kernel(x_ref, w_ref, o_ref, trace_ref):
     # ---- device-side event record (fine-grained tier) ----------------------
     bytes_read = x.size * x.dtype.itemsize + w.size * w.dtype.itemsize
     bytes_written = o_ref.size * o_ref.dtype.itemsize
-    trace_ref[0, 0] = i
-    trace_ref[0, 1] = j
-    trace_ref[0, 2] = bytes_read
-    trace_ref[0, 3] = bytes_written
+    row = 4 * (i * pl.num_programs(1) + j)
+    trace_ref[row] = i
+    trace_ref[row + 1] = j
+    trace_ref[row + 2] = bytes_read
+    trace_ref[row + 3] = bytes_written
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def matmul_traced(x: jax.Array, w: jax.Array, interpret: bool = False):
+def matmul_traced_pallas(x: jax.Array, w: jax.Array, interpret: bool = False):
     """(M,K)@(K,N) with an on-device access-record trace.
 
     Returns (out f32[M,N], trace int32[n_grid_steps, 4])."""
@@ -60,15 +62,15 @@ def matmul_traced(x: jax.Array, w: jax.Array, interpret: bool = False):
         ],
         out_specs=[
             pl.BlockSpec((BM, BN), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 4), lambda i, j: (i * (n // BN) + j, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, n), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0] * grid[1], 4), jnp.int32),
+            jax.ShapeDtypeStruct((grid[0] * grid[1] * 4,), jnp.int32),
         ],
         interpret=interpret,
     )(x, w)
-    return out, trace
+    return out, trace.reshape(-1, 4)
 
 
 def matmul_traced_ref(x: jax.Array, w: jax.Array):

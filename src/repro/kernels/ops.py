@@ -1,10 +1,11 @@
 """Jitted dispatch wrappers for the PASTA analysis kernels.
 
-Dispatch policy:
+Dispatch policy (:func:`backend`):
 
-  * on TPU: the Pallas kernels (compiled);
-  * ``REPRO_PALLAS_INTERPRET=1``: Pallas kernels in interpret mode (CPU
-    correctness path used by the test sweeps);
+  * on TPU: the Pallas kernels, compiled — always; nothing off the chip's
+    own compiler is ever chosen there;
+  * off the chip with ``REPRO_PALLAS_INTERPRET=1``: Pallas kernels in
+    interpret mode (CPU correctness path used by the test sweeps);
   * otherwise: the pure-jnp oracles in :mod:`repro.kernels.ref` compiled by
     XLA — still the device-resident (Fig. 2b) analysis model, just without
     hand tiling.
@@ -28,17 +29,19 @@ from .trace_aggregate import (FUSE_BLOCK_T, FUSE_BLOCK_K, FUSE_VMEM_BUDGET,
                               trace_aggregate_pallas)
 from .hotness import BLOCK_T as HOT_BLOCK_T, BLOCK_B as HOT_BLOCK_B
 from .hotness import hotness_histogram_pallas
+from .instrumented_matmul import matmul_traced_pallas, matmul_traced_ref
 
 UNIT_SHIFT = 9                 # 512-byte address units
 BLOCK_SHIFT = 12               # 2 MiB blocks = 4096 units = 2**12
 _I32_MAX = np.int32(2**31 - 1)
 
 
-def _backend() -> str:
-    if os.environ.get("REPRO_PALLAS_INTERPRET") == "1":
-        return "interpret"
+def backend() -> str:
+    """``"pallas"`` on TPU; off it ``"interpret"`` or ``"ref"``."""
     if jax.default_backend() == "tpu":
         return "pallas"
+    if os.environ.get("REPRO_PALLAS_INTERPRET") == "1":
+        return "interpret"
     return "ref"
 
 
@@ -50,12 +53,19 @@ _ref_trace_aggregate = jax.jit(
     static_argnames=("n_blocks", "n_tbins", "block_shift"))
 
 
-def _pad_to(x: np.ndarray, mult: int, value) -> np.ndarray:
+def _padded(n: int, tile: int) -> int:
+    """``n`` rounded up to ``tile`` × a power of two: traces and object
+    tables of every length then share a few compiled kernel shapes instead
+    of compiling one per length."""
+    return tile * (1 << max(-(-n // tile) - 1, 0).bit_length())
+
+
+def _pad_to(x: np.ndarray, tile: int, value) -> np.ndarray:
     n = x.shape[0]
-    pad = (-n) % mult
-    if pad == 0:
+    target = _padded(n, tile)
+    if target == n:
         return x
-    return np.concatenate([x, np.full(pad, value, dtype=x.dtype)])
+    return np.concatenate([x, np.full(target - n, value, dtype=x.dtype)])
 
 
 def _to_units(addrs_bytes) -> np.ndarray:
@@ -71,8 +81,8 @@ def object_histogram(addrs_bytes, starts_bytes, ends_bytes):
     s = _to_units(starts_bytes)
     e = _to_units(ends_bytes)
     assert a.shape[0] < 2**24, "split traces >16M records for exact f32 accum"
-    backend = _backend()
-    if backend == "ref":
+    be = backend()
+    if be == "ref":
         return np.asarray(_ref_object_histogram(
             jnp.asarray(a), jnp.asarray(s), jnp.asarray(e))).astype(np.int64)
     a = _pad_to(a, AGG_BLOCK_T, -1)
@@ -80,7 +90,7 @@ def object_histogram(addrs_bytes, starts_bytes, ends_bytes):
     e = _pad_to(e, AGG_BLOCK_K, _I32_MAX)
     counts = object_histogram_pallas(jnp.asarray(a), jnp.asarray(s),
                                      jnp.asarray(e),
-                                     interpret=backend == "interpret")
+                                     interpret=be == "interpret")
     return np.asarray(counts[:k]).astype(np.int64)
 
 
@@ -94,8 +104,8 @@ def hotness_histogram(addrs_bytes, times, base_addr: int, n_blocks: int,
     tb = np.minimum((t / max(t_max, 1e-12) * n_tbins).astype(np.int32),
                     n_tbins - 1)
     base = np.int32(int(base_addr) >> UNIT_SHIFT)
-    backend = _backend()
-    if backend == "ref":
+    be = backend()
+    if be == "ref":
         out = _ref_hotness(jnp.asarray(a), jnp.asarray(tb), base,
                            n_blocks=n_blocks, n_tbins=n_tbins,
                            block_shift=block_shift)
@@ -105,7 +115,7 @@ def hotness_histogram(addrs_bytes, times, base_addr: int, n_blocks: int,
     nb_p = n_blocks + ((-n_blocks) % HOT_BLOCK_B)
     out = hotness_histogram_pallas(jnp.asarray(a_p), jnp.asarray(tb_p), base,
                                    nb_p, n_tbins, block_shift,
-                                   interpret=backend == "interpret")
+                                   interpret=be == "interpret")
     return np.asarray(out[:, :n_blocks]).astype(np.int64)
 
 
@@ -116,9 +126,9 @@ def can_fuse(n_objects: int, n_blocks: int, n_tbins: int) -> bool:
     set must fit the VMEM budget — limits the tiled two-pass kernels do not
     have; callers fall back to the separate kernels when this returns False.
     The jnp oracle backend has no such limits."""
-    if _backend() == "ref":
+    if backend() == "ref":
         return True
-    k_p = n_objects + ((-n_objects) % FUSE_BLOCK_K)
+    k_p = _padded(n_objects, FUSE_BLOCK_K)
     nb_p = n_blocks + ((-n_blocks) % HOT_BLOCK_B)
     return fuse_vmem_bytes(k_p, nb_p, n_tbins) <= FUSE_VMEM_BUDGET
 
@@ -140,8 +150,8 @@ def trace_aggregate(addrs_bytes, times, starts_bytes, ends_bytes,
                     n_tbins - 1)
     base = np.int32(int(base_addr) >> UNIT_SHIFT)
     assert a.shape[0] < 2**24, "split traces >16M records for exact f32 accum"
-    backend = _backend()
-    if backend == "ref":
+    be = backend()
+    if be == "ref":
         counts, hist = _ref_trace_aggregate(
             jnp.asarray(a), jnp.asarray(tb), jnp.asarray(s), jnp.asarray(e),
             base, n_blocks=n_blocks, n_tbins=n_tbins, block_shift=block_shift)
@@ -155,6 +165,16 @@ def trace_aggregate(addrs_bytes, times, starts_bytes, ends_bytes,
     counts, hist = trace_aggregate_pallas(
         jnp.asarray(a_p), jnp.asarray(tb_p), jnp.asarray(s_p),
         jnp.asarray(e_p), base, block_shift, n_blocks=nb_p, n_tbins=n_tbins,
-        interpret=backend == "interpret")
+        interpret=be == "interpret")
     return (np.asarray(counts[:k]).astype(np.int64),
             np.asarray(hist[:, :n_blocks]).astype(np.int64))
+
+
+def matmul_traced(x: jax.Array, w: jax.Array):
+    """(M,K)@(K,N) → ``(f32[M,N], int32[grid steps, 4] access trace)`` from
+    the instrumented kernel (or its analytic oracle on the ``ref``
+    backend)."""
+    be = backend()
+    if be == "ref":
+        return matmul_traced_ref(x, w)
+    return matmul_traced_pallas(x, w, interpret=be == "interpret")

@@ -27,9 +27,21 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .hotness import pad_tbins, tile_hotness
 
 BLOCK_T = 2048     # trace records per tile
 BLOCK_K = 512      # objects per tile
+
+
+def tile_counts(a, s, e):
+    """One trace tile's per-object hit counts: ``a`` is the (1, T) address
+    row, ``s``/``e`` the (1, K) range rows → f32 (1, K)."""
+    col = a[0, :][:, None]                     # (T, 1) int32 column
+    in_range = ((col >= s) & (col < e)).astype(jnp.float32)      # (T, K)
+    ones = jnp.ones((1, a.shape[1]), dtype=jnp.float32)
+    return jax.lax.dot(ones, in_range, preferred_element_type=jnp.float32)
 
 
 def _kernel(addrs_ref, starts_ref, ends_ref, counts_ref):
@@ -37,14 +49,8 @@ def _kernel(addrs_ref, starts_ref, ends_ref, counts_ref):
     def _init():
         counts_ref[...] = jnp.zeros_like(counts_ref)
 
-    a = addrs_ref[0, :]                        # (T,)
-    s = starts_ref[0, :]                       # (K,)
-    e = ends_ref[0, :]
-    in_range = ((a[:, None] >= s[None, :]) &
-                (a[:, None] < e[None, :])).astype(jnp.float32)   # (T, K)
-    ones = jnp.ones((1, a.shape[0]), dtype=jnp.float32)
-    counts_ref[...] += jax.lax.dot(ones, in_range,
-                                   preferred_element_type=jnp.float32)
+    counts_ref[...] += tile_counts(addrs_ref[...], starts_ref[...],
+                                   ends_ref[...])
 
 
 #: trace records per tile for the fused counts+hotness kernel; smaller than
@@ -53,8 +59,11 @@ FUSE_BLOCK_T = 1024
 #: object-table padding granularity for the fused kernel (full table
 #: resident in VMEM, so pad to the 128-lane tile only)
 FUSE_BLOCK_K = 128
-#: conservative slice of the ~16 MiB VMEM left for the fused kernel's
-#: working set (accumulators + one-hot operands + compiler temporaries)
+#: conservative slice of the ~16 MiB scoped VMEM left for the fused
+#: kernel's working set (accumulators + one-hot operands + compiler
+#: temporaries).  A routing threshold, not a compiler limit: the v5e
+#: compiler accepted the fused kernel at every size probed, up to a
+#: :func:`fuse_vmem_bytes` estimate of 1 GiB.
 FUSE_VMEM_BUDGET = 12 * 1024 * 1024
 
 
@@ -70,7 +79,7 @@ def fuse_vmem_bytes(k: int, n_blocks: int, n_tbins: int) -> int:
     return resident + 2 * transient
 
 
-def _fused_kernel(addrs_ref, tbins_ref, starts_ref, ends_ref, meta_ref,
+def _fused_kernel(meta_ref, addrs_ref, tbins_ref, starts_ref, ends_ref,
                   counts_ref, hist_ref):
     """One stream over the trace, two accumulators: per-object counts and
     the [time-bin × block] hotness map share each (1, FUSE_BLOCK_T) addr
@@ -81,29 +90,11 @@ def _fused_kernel(addrs_ref, tbins_ref, starts_ref, ends_ref, meta_ref,
         counts_ref[...] = jnp.zeros_like(counts_ref)
         hist_ref[...] = jnp.zeros_like(hist_ref)
 
-    a = addrs_ref[0, :]                        # (T,) shared addr tile
-    # --- accumulator 1: per-object counts (histogram-as-matmul) ----------
-    s = starts_ref[0, :]                       # (K,)
-    e = ends_ref[0, :]
-    in_range = ((a[:, None] >= s[None, :]) &
-                (a[:, None] < e[None, :])).astype(jnp.float32)   # (T, K)
-    ones = jnp.ones((1, a.shape[0]), dtype=jnp.float32)
-    counts_ref[...] += jax.lax.dot(ones, in_range,
-                                   preferred_element_type=jnp.float32)
-    # --- accumulator 2: time×block hotness (rank-expanding one-hots) ------
-    base = meta_ref[0, 0]
-    shift = meta_ref[0, 1]
+    a = addrs_ref[...]                         # (1, T) shared addr tile
+    counts_ref[...] += tile_counts(a, starts_ref[...], ends_ref[...])
     n_tbins, n_blocks = hist_ref.shape
-    tb = tbins_ref[0, :]
-    blk = jax.lax.shift_right_arithmetic(a - base, shift)
-    valid = (blk >= 0) & (blk < n_blocks) & \
-            (tb >= 0) & (tb < n_tbins) & (a >= 0)
-    t_iota = jax.lax.broadcasted_iota(jnp.int32, (a.shape[0], n_tbins), 1)
-    b_iota = jax.lax.broadcasted_iota(jnp.int32, (a.shape[0], n_blocks), 1)
-    onehot_t = ((tb[:, None] == t_iota) & valid[:, None]).astype(jnp.float32)
-    onehot_b = (blk[:, None] == b_iota).astype(jnp.float32)
-    hist_ref[...] += jax.lax.dot(onehot_t.T, onehot_b,
-                                 preferred_element_type=jnp.float32)
+    hist_ref[...] += tile_hotness(a, tbins_ref[...], meta_ref[0, 0],
+                                  meta_ref[0, 1], 0, n_tbins, n_blocks)
 
 
 @functools.partial(jax.jit, static_argnames=("n_blocks", "n_tbins",
@@ -124,30 +115,31 @@ def trace_aggregate_pallas(addrs: jax.Array, tbins: jax.Array,
     assert n % FUSE_BLOCK_T == 0 and k % FUSE_BLOCK_K == 0, (n, k)
     assert fuse_vmem_bytes(k, n_blocks, n_tbins) <= FUSE_VMEM_BUDGET, \
         f"fused working set exceeds VMEM budget: {(k, n_blocks, n_tbins)}"
+    nt_p = pad_tbins(n_tbins)
     grid = (n // FUSE_BLOCK_T,)
     meta = jnp.array([[base, block_shift]], dtype=jnp.int32)
     counts, hist = pl.pallas_call(
         _fused_kernel,
         grid=grid,
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, FUSE_BLOCK_T), lambda nn: (0, nn)),
             pl.BlockSpec((1, FUSE_BLOCK_T), lambda nn: (0, nn)),
             pl.BlockSpec((1, k), lambda nn: (0, 0)),
             pl.BlockSpec((1, k), lambda nn: (0, 0)),
-            pl.BlockSpec((1, 2), lambda nn: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, k), lambda nn: (0, 0)),
-            pl.BlockSpec((n_tbins, n_blocks), lambda nn: (0, 0)),
+            pl.BlockSpec((nt_p, n_blocks), lambda nn: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, k), jnp.float32),
-            jax.ShapeDtypeStruct((n_tbins, n_blocks), jnp.float32),
+            jax.ShapeDtypeStruct((nt_p, n_blocks), jnp.float32),
         ],
         interpret=interpret,
-    )(addrs.reshape(1, n), tbins.reshape(1, n), starts.reshape(1, k),
-      ends.reshape(1, k), meta)
-    return counts[0], hist
+    )(meta, addrs.reshape(1, n), tbins.reshape(1, n), starts.reshape(1, k),
+      ends.reshape(1, k))
+    return counts[0], hist[:n_tbins]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -1,4 +1,7 @@
 import os
+# a CPU virtual mesh: pinned to the host so it never contends for a chip
+# that the parent process holds
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell and
@@ -253,8 +256,10 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                         training=shape.kind == "train",
                         n_active_params=cfg.n_active_params
                         if cfg.family == "moe" else None)
+    # the analytic model's target chip is named, not inherited
     rl = RL.roofline(stats.flops, stats.hbm_bytes,
                      stats.total_collective_bytes,
+                     RL.peaks(RL.ANALYTIC_TARGET),
                      model_flops_per_chip=mf / chips)
     out = {
         "arch": arch, "shape": shape_name, "mesh": mesh_name(mesh),
@@ -330,6 +335,9 @@ def main():
     ap.add_argument("--lint-baseline", default=None,
                     help="baseline JSON of accepted findings to suppress")
     args = ap.parse_args()
+    print(f"[dryrun] platform={jax.devices()[0].platform} with "
+          f"{jax.device_count()} virtual devices (JAX_PLATFORMS=cpu): "
+          f"compiles and analytic models only, no chip")
 
     overrides = {}
     if args.set:

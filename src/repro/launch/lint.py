@@ -41,6 +41,9 @@ def _early_devices(argv) -> int:
 
 
 N_DEVICES = _early_devices(sys.argv)
+# a CPU virtual mesh: pinned to the host so it never contends for a chip
+# that the parent process holds
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     f"--xla_force_host_platform_device_count={N_DEVICES}")
 
@@ -55,6 +58,7 @@ import repro.configs as configs                             # noqa: E402
 from repro import analysis                                  # noqa: E402
 from repro.dist.sharding import (DEFAULT_RULES, ShardingRules,  # noqa: E402
                                  get_rules, set_mesh)
+from repro.launch.mesh import make_mesh                     # noqa: E402
 from repro.train import OptConfig, make_train_step, train_shardings  # noqa: E402
 from repro.train.trainer import batch_shardings             # noqa: E402
 
@@ -64,13 +68,15 @@ SMOKE_ARCHS = ("qwen3-32b", "mamba2-2.7b", "dbrx-132b")
 #: pass spec calibrated for the reduced smoke grid.  At smoke scale every
 #: individual collective looks exposed (there is almost no compute to hide
 #: behind), so exposed-collectives gates on the *aggregate* DCI exposure
-#: instead: the bucketed overlap pipeline measures <=0.7us across the
-#: three archs where the blocking sync measures >=1.3us — the 1us budget
-#: sits between them.  dtype-promotion's jaxpr floor is raised above the
+#: instead, counting every message (``min_bytes=0``): per-message alpha
+#: latency is what bucketing removes.  On jax 0.9's HLO the modelled DCI
+#: exposure of the three archs is 14.7-17.9 us with the bucketed overlap
+#: pipeline and 53-60 us with the blocking sync — the 30 us budget sits
+#: between them.  dtype-promotion's jaxpr floor is raised above the
 #: ~32k-element dequantize upcasts the compressed sync performs on
 #: purpose (a real f32 activation leak is megabytes, not kilobytes).
 SMOKE_SPEC = ("exposed-collectives:link=dci,threshold_frac=1.1,"
-              "total_budget_s=1e-06,"
+              "min_bytes=0,total_budget_s=3e-05,"
               "implicit-reshard,"
               "dtype-promotion:min_numel_jaxpr=65536,"
               "peak-memory,host-sync")
@@ -88,7 +94,7 @@ def smoke_cell(arch: str, *, overlap_sync=True, rules_patch=None,
     """Compile one reduced train cell on the virtual mesh and lint it."""
     cfg = configs.reduced(configs.get(arch))
     opt_cfg = OptConfig()
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     rules = None
     if rules_patch:
         rules = ShardingRules({**DEFAULT_RULES, **rules_patch})
@@ -197,6 +203,8 @@ def main() -> int:
                          "reduced smoke grid")
     args = ap.parse_args()
 
+    print(f"[lint] platform={jax.devices()[0].platform} with "
+          f"{jax.device_count()} virtual devices (JAX_PLATFORMS=cpu)")
     overlap = {"overlap": True, "blocking": False, "auto": None}[args.overlap]
     rules_patch = None
     if args.seed_defect == "reshard":

@@ -28,7 +28,8 @@ a hard per-request deadline onto every trace request's SLO (status
 ``health`` section plus a top-level ``request_states`` map, so a chaos
 run's outcome is machine-checkable against its fault-free twin.
 
-``--compile-cache <dir>`` turns on the persistent XLA compilation cache
+The persistent XLA compilation cache is always on: in
+``JAX_COMPILATION_CACHE_DIR`` when that is set, else in ``<repo>/.jax_cache``
 (cold run compiles and populates; warm runs skip XLA) — ``compile_s`` in
 the JSON summary shows the cold-vs-warm difference.
 
@@ -121,9 +122,6 @@ def _parse():
     ap.add_argument("--deadline-s", type=float, default=None,
                     help="hard per-request deadline stamped onto every "
                          "trace request's SLO (status 'timeout' on expiry)")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent XLA compilation cache directory "
-                         "(warm runs skip recompiles; see compile_s)")
     ap.add_argument("--no-warmup", action="store_true",
                     help="skip pre-trace jit warmup (TTFT/TPOT will then "
                          "include compile time)")
@@ -159,6 +157,54 @@ def make_trace(args, vocab: int):
     return prompts, arrivals
 
 
+def drive(engine, trace, temperature: float = 0.0):
+    """Open-loop replay: submit each ``TraceRequest`` at its arrival time
+    and tick ``engine.step()`` until the trace drains.  Returns ``(rids,
+    {rid: generated tokens}, wall seconds)``."""
+    from repro.serve import SamplingParams
+    t0 = time.perf_counter()
+    pending = [(t.arrival_s, t) for t in trace]
+    rids = []
+    outputs = {}            # collected at retirement (pruning-safe)
+    while pending or engine.has_work:
+        now = time.perf_counter() - t0
+        while pending and pending[0][0] <= now:
+            t = pending.pop(0)[1]
+            rids.append(engine.submit(
+                t.prompt,
+                SamplingParams(max_new_tokens=t.max_new_tokens,
+                               temperature=temperature),
+                slo=t.slo))
+        if engine.has_work:
+            for rid in engine.step()["finished"]:
+                outputs[rid] = list(engine.requests[rid].tokens)
+        elif pending:
+            time.sleep(min(pending[0][0] - now, 0.05))
+    return rids, outputs, time.perf_counter() - t0
+
+
+def capture_decode(session, engine, params) -> None:
+    """Feed the fused decode (or speculative verify) step's compiled HLO
+    into the fleet session, so kernel_freq and the roofline see it."""
+    import jax.numpy as jnp
+    import numpy as np
+    slots = engine.pool.slots
+    if engine.paged:
+        span = engine.pool.blocks_per_seq * engine.pool.block_size
+        cache = engine.pool.cache_view(np.full((slots,), span, np.int32))
+    else:
+        cache = engine.pool.cache
+    if engine.spec_k:
+        compiled = engine._verify.lower(
+            params, cache, jnp.zeros((slots, engine.spec_k + 1), jnp.int32),
+            jnp.asarray(engine._verify_idx)).compile()
+    else:
+        compiled = engine._decode.lower(
+            params, cache, jnp.zeros((slots, 1), jnp.int32)).compile()
+    session.capture_compiled(compiled, label="serve.decode",
+                             steps=max(engine.decode_steps, 1))
+
+
 def _short(data: dict) -> dict:
     return {k: v for k, v in data.items()
             if k not in ("series", "top", "by_label", "by_request")}
@@ -177,28 +223,23 @@ def main():
     import jax
     import numpy as np
 
-    if args.compile_cache:
-        # persistent XLA compile cache: cold runs populate, warm runs skip
-        # XLA entirely (min thresholds zeroed so even the small reduced
-        # configs cache — the default 1s floor would skip them)
-        cache_dir = os.path.abspath(args.compile_cache)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    from repro.launch.compile_cache import setup_compile_cache
+    cache_dir = setup_compile_cache()
 
     import dataclasses
 
     import repro.configs as configs
     import repro.core as pasta
     from repro.dist.sharding import set_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models import init_params
-    from repro.serve import SamplingParams, ServeEngine, SLOSpec, traffic
+    from repro.serve import ServeEngine, SLOSpec, traffic
 
     cfg = configs.get(args.arch)
     if args.reduced:
         cfg = configs.reduced(cfg)
     d, m = (int(x) for x in args.mesh.split("x"))
-    mesh = jax.make_mesh((d, m), ("data", "model")) if d * m > 1 else None
+    mesh = make_mesh((d, m), ("data", "model")) if d * m > 1 else None
     set_mesh(mesh)
 
     vocab = max(cfg.vocab_size, 2)
@@ -269,25 +310,7 @@ def main():
             compile_s = wu["compile_s"]
             print(f"[serve] warmup: {len(wu['warmed'])} shapes compiled "
                   f"in {compile_s:.2f}s (excluded from the trace clock)")
-        t0 = time.perf_counter()
-        pending = [(t.arrival_s, t) for t in trace]
-        rids = []
-        outputs = {}            # collected at retirement (pruning-safe)
-        while pending or engine.has_work:
-            now = time.perf_counter() - t0
-            while pending and pending[0][0] <= now:
-                t = pending.pop(0)[1]
-                rids.append(engine.submit(
-                    t.prompt,
-                    SamplingParams(max_new_tokens=t.max_new_tokens,
-                                   temperature=args.temperature),
-                    slo=t.slo))
-            if engine.has_work:
-                for rid in engine.step()["finished"]:
-                    outputs[rid] = list(engine.requests[rid].tokens)
-            elif pending:
-                time.sleep(min(pending[0][0] - now, 0.05))
-        dt = time.perf_counter() - t0
+        rids, outputs, dt = drive(engine, trace, args.temperature)
         n_tok = sum(len(t) for t in outputs.values())
         print(f"[serve] {len(rids)} requests, {n_tok} tokens in {dt:.2f}s "
               f"({n_tok / dt:.1f} tok/s), max_slots={args.max_slots}, "
@@ -317,29 +340,7 @@ def main():
             print(f"[serve] sample: {outputs[done_rids[0]][:12]}")
         else:
             print("[serve] sample: <no finished requests>")
-        try:
-            # fleet kernel_freq etc. see the fused decode step's compiled HLO
-            import jax.numpy as jnp
-            if engine.paged:
-                span = engine.pool.blocks_per_seq * engine.pool.block_size
-                cache = engine.pool.cache_view(
-                    np.full((args.max_slots,), span, np.int32))
-            else:
-                cache = engine.pool.cache
-            if engine.spec_k:
-                compiled = engine._verify.lower(
-                    params, cache,
-                    jnp.zeros((args.max_slots, engine.spec_k + 1),
-                              jnp.int32),
-                    jnp.asarray(engine._verify_idx)).compile()
-            else:
-                compiled = engine._decode.lower(
-                    params, cache,
-                    jnp.zeros((args.max_slots, 1), jnp.int32)).compile()
-            session.capture_compiled(compiled, label="serve.decode",
-                                     steps=max(engine.decode_steps, 1))
-        except Exception as e:                              # noqa: BLE001
-            print(f"[serve] decode capture skipped: {e}", file=sys.stderr)
+        capture_decode(session, engine, params)
         reports = session.reports()
 
     serving = reports["serving"].data if "serving" in reports else {}
@@ -384,7 +385,7 @@ def main():
                 "chaos": args.chaos,
                 "chaos_seed": args.chaos_seed,
                 "deadline_s": args.deadline_s,
-                "compile_cache": args.compile_cache,
+                "compile_cache": cache_dir,
             },
             "summary": {
                 "wall_s": dt,

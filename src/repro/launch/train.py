@@ -46,9 +46,6 @@ def _parse():
     ap.add_argument("--inject-failure-at", type=int, default=None)
     ap.add_argument("--data", default="", help="token .bin file (synthetic "
                                                "if empty)")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent XLA compilation cache directory — "
-                         "warm restarts skip the train-step recompile")
     ap.add_argument("--seed", type=int, default=0)
     return ap.parse_args()
 
@@ -62,18 +59,14 @@ def main():
     import jax
     import numpy as np
 
-    if args.compile_cache:
-        # persistent XLA compile cache: a restarted run (same config, same
-        # mesh) skips the train-step compile entirely — min thresholds
-        # zeroed so the small reduced configs cache too
-        cache_dir = os.path.abspath(args.compile_cache)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    from repro.launch.compile_cache import setup_compile_cache
+    # a restarted run (same config, same mesh) skips the train-step compile
+    cache_dir = setup_compile_cache()
 
     import repro.configs as configs
     import repro.core as pasta
     from repro.dist.sharding import set_mesh
+    from repro.launch.mesh import make_mesh
     from repro.train import (OptConfig, make_train_step, train_shardings,
                              DataConfig, make_source, LoopConfig, TrainLoop,
                              checkpoint as ckpt)
@@ -87,7 +80,7 @@ def main():
 
     dims = tuple(int(x) for x in args.mesh.split("x"))
     axes = ("pod", "data", "model")[-len(dims):]
-    mesh = jax.make_mesh(dims, axes) if np.prod(dims) > 1 else None
+    mesh = make_mesh(dims, axes) if np.prod(dims) > 1 else None
     set_mesh(mesh)
     overlap_sync = {"auto": None, "blocking": False,
                     "overlap": True}[args.overlap_sync]
@@ -149,7 +142,7 @@ def main():
                                                metrics_cb)
 
         # post-run: capture the compiled artifact into the event stream
-        # (timed: with --compile-cache this is the warm-vs-cold signal)
+        # (timed: against the persistent cache, the warm-vs-cold signal)
         example = place_batch(source.batch_at(0))
         t_c = time.perf_counter()
         compiled = jitted.lower(params, opt_state, example).compile()
@@ -165,9 +158,8 @@ def main():
         print(f"  {name}: {short}")
     if loop.stragglers:
         print(f"[train] straggler steps detected: {loop.stragglers}")
-    cached = " (compile cache: " + args.compile_cache + ")" \
-        if args.compile_cache else ""
-    print(f"[train] train_step compile_s={compile_s:.3f}{cached}")
+    print(f"[train] train_step compile_s={compile_s:.3f} "
+          f"(compile cache: {cache_dir})")
     print(f"[train] done at step {step}; restarts={loop.restarts}")
     return 0
 

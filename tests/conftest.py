@@ -2,22 +2,8 @@
 benches must see the real single CPU device; multi-device distribution tests
 spawn subprocesses that set XLA_FLAGS themselves (see test_dist.py)."""
 
-import importlib.util
-import os
-import sys
-
 import numpy as np
 import pytest
-
-try:
-    import hypothesis  # noqa: F401  (real package wins when installed)
-except ImportError:
-    _path = os.path.join(os.path.dirname(__file__), "_hypothesis_stub.py")
-    _spec = importlib.util.spec_from_file_location("hypothesis", _path)
-    _mod = importlib.util.module_from_spec(_spec)
-    sys.modules["hypothesis"] = _mod
-    _spec.loader.exec_module(_mod)
-    sys.modules["hypothesis.strategies"] = _mod.strategies
 
 from repro.core import events as _events_mod
 from repro.core import session as _session_mod

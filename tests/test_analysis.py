@@ -644,14 +644,14 @@ def test_e2e_blocking_sync_trips_dci_budget_overlap_does_not():
         from repro.launch import lint
 
         spec = ("exposed-collectives:link=dci,threshold_frac=1.1,"
-                "total_budget_s=1e-06")
+                "min_bytes=0,total_budget_s=3e-05")
         ok = lint.smoke_cell("qwen3-32b", overlap_sync=True, spec=spec)
         assert not ok.by_pass("exposed-collectives"), ok.to_json()
 
         bad = lint.smoke_cell("qwen3-32b", overlap_sync=False, spec=spec)
         (h,) = bad.by_pass("exposed-collectives")
         assert h.instruction == "total[dci]"
-        assert h.data["total_exposed_s"] > 1e-06
+        assert h.data["total_exposed_s"] > 3e-05
         print("OK exposed_us=", h.data["total_exposed_s"] * 1e6)
     """)
     assert "OK" in out

@@ -323,3 +323,22 @@ def test_processor_fused_single_pass_matches_two_pass(handler, rng):
         block_shift=hp["block_shift"])
     np.testing.assert_array_equal(fused.attrs["object_counts"], c2)
     np.testing.assert_array_equal(fused.attrs["hotness_map"], h2)
+
+
+def test_hotness_tool_grows_to_processor_map(handler, rng):
+    """A processor configured for more blocks or bins than the tool's own
+    matrix (a 1.6B model's ~3.3k 2 MiB blocks against the default 1024)
+    must accumulate every access instead of failing to broadcast."""
+    tool = pasta.HotnessTool()                     # 64 x 1024 by default
+    hp = {"base": 2 << 20, "n_blocks": 3328, "n_tbins": 8, "t_max": 1.0}
+    proc = pasta.EventProcessor(handler, tools=[tool], hotness=hp)
+    starts = np.array([2 << 20, 6000 << 20])
+    ends = starts + (1 << 20)
+    addrs = np.concatenate([rng.integers(starts[0], ends[0], 300),
+                            rng.integers(starts[1], ends[1], 100)])
+    handler.trace_buffer(addrs, name="k", objects=list(zip(starts, ends)),
+                         object_sizes=[1 << 20, 1 << 20], time=0.5)
+    proc.close()
+    rep = tool.finalize()
+    assert rep["total_accesses"] == 400
+    assert rep["hot_matrix_shape"] == [64, 3328]

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import repro.core as pasta
 from repro.core.events import Event, EventKind
 from repro.core.tools import offload
+from repro.launch.mesh import make_mesh
 
 
 # ------------------------------------------------------------- annotations
@@ -184,7 +185,7 @@ def test_hlo_walker_collectives(handler):
     import jax.sharding as sh
     if jax.device_count() < 1:
         pytest.skip("no devices")
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     spec = sh.NamedSharding(mesh, sh.PartitionSpec(None, "model"))
 
     def f(x):
@@ -193,6 +194,33 @@ def test_hlo_walker_collectives(handler):
         jax.ShapeDtypeStruct((32, 32), jnp.float32)).compile()
     stats = handler.capture_compiled(compiled)
     assert stats.flops > 0                        # parses without error
+
+
+#: matmuls as the TPU compiler emits them: a projection whose head axis
+#: rides a fully padded window, and attention scores whose batch axes ride
+#: dilated, strided windows (one real tap per output along each)
+TPU_CONV_HLO = """
+HloModule tpu_convs
+
+ENTRY %main (p0: bf16[8,1024,768,1], p1: bf16[768,12,64,1], p2: bf16[8,12,64,1024], p3: bf16[8,1024,12,64]) -> (bf16[8,1024,12,64], bf16[8,12,1024,1024]) {
+  %p0 = bf16[8,1024,768,1]{3,2,1,0} parameter(0)
+  %p1 = bf16[768,12,64,1]{3,2,1,0} parameter(1)
+  %p2 = bf16[8,12,64,1024]{3,2,1,0} parameter(2)
+  %p3 = bf16[8,1024,12,64]{3,2,1,0} parameter(3)
+  %proj = bf16[8,1024,12,64]{3,2,1,0} convolution(bf16[8,1024,768,1]{3,2,1,0} %p0, bf16[768,12,64,1]{3,2,1,0} %p1), window={size=1x12 pad=0_0x11_11 rhs_reversal=0x1}, dim_labels=0bf1_i1o0->0b1f
+  %scores = bf16[8,12,1024,1024]{3,2,1,0} convolution(bf16[8,12,64,1024]{3,2,1,0} %p2, bf16[8,1024,12,64]{3,2,1,0} %p3), window={size=8x12 stride=7x11 lhs_dilate=8x12}, dim_labels=01fb_0o1i->01bf
+  ROOT %t = (bf16[8,1024,12,64]{3,2,1,0}, bf16[8,12,1024,1024]{3,2,1,0}) tuple(%proj, %scores)
+}
+"""
+
+
+def test_hlo_walker_tpu_convolution_flops():
+    from repro.core.hlo import analyze_text
+    stats = analyze_text(TPU_CONV_HLO)
+    proj = 2 * (8 * 1024) * 768 * (12 * 64)        # tokens x d x heads*hd
+    scores = 2 * (8 * 12) * 1024 * 1024 * 64       # B*H x S x S x hd
+    assert stats.flops == pytest.approx(proj + scores)
+    assert not stats.warnings
 
 
 def test_shape_bytes():

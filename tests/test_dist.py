@@ -15,7 +15,8 @@ def run_sub(code: str, devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+    code = "from repro.launch.mesh import make_mesh\n" + textwrap.dedent(code)
+    r = subprocess.run([sys.executable, "-c", code],
                        capture_output=True, text=True, env=env, timeout=600)
     assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
     return r.stdout
@@ -44,7 +45,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
         p1, o1, m1 = jax.jit(step)(params, opt, batch)
         loss1 = float(m1["loss"])
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         set_mesh(mesh)
         p_sh, o_sh, _, _ = train_shardings(mesh, cfg, opt_cfg)
         step2 = make_train_step(cfg, opt_cfg, microbatches=2)
@@ -70,7 +71,7 @@ def test_compressed_psum_matches_plain_within_quant_error():
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.dist.collectives import (compressed_psum, plain_psum,
                                             make_pod_sync)
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         rng = np.random.default_rng(0)
         g = jax.device_put(rng.standard_normal((8, 16)).astype(np.float32),
                            NamedSharding(mesh, P("data", "model")))
@@ -99,7 +100,7 @@ def test_compressed_psum_flat_error_across_pod_counts():
         for npods, spec in [(2, ((2, 4), ("pod", "data"))),
                             (4, ((4, 2), ("pod", "data"))),
                             (8, ((8,), ("pod",)))]:
-            mesh = jax.make_mesh(*spec)
+            mesh = make_mesh(*spec)
             a = jax.jit(lambda t: make_pod_sync(mesh, compressed=True)(
                 {"g": t}))(g)["g"]
             b = jax.jit(lambda t: make_pod_sync(mesh, compressed=False)(
@@ -126,7 +127,7 @@ def test_psum_start_wait_roundtrip_exact():
         from jax.sharding import PartitionSpec as P
         from jax.experimental.shard_map import shard_map
         from repro.dist.collectives import psum_start, psum_wait
-        mesh = jax.make_mesh((8,), ("pod",))
+        mesh = make_mesh((8,), ("pod",))
         rng = np.random.default_rng(1)
         xs = [jnp.asarray(rng.standard_normal(s).astype(np.float32))
               for s in ((5, 7), (13,), (2, 3, 4))]   # none divide by 8
@@ -180,7 +181,7 @@ def test_overlap_sync_train_step_matches_baseline():
             params, opt, batch)
         loss0, gn0 = float(m0["loss"]), float(m0["grad_norm"])
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         set_mesh(mesh)
         p_sh, o_sh, _, _ = train_shardings(mesh, cfg, opt_cfg)
         b_sh = batch_shardings(mesh, batch, include_pod=False)
@@ -220,7 +221,7 @@ def test_pipeline_forward_matches_sequential():
         def block(w, x):
             return jnp.tanh(x @ w)
 
-        mesh = jax.make_mesh((4, 2), ("pipe", "data"))
+        mesh = make_mesh((4, 2), ("pipe", "data"))
         fn = make_pipelined_fn(mesh, block, n_stages, lps)
         xs = jnp.asarray(rng.standard_normal((M, 4, 16)), jnp.float32)
         got = jax.jit(fn)(Ws, xs)
@@ -263,7 +264,7 @@ def test_long_context_decode_seq_sharded_cache():
         lg_ref, _ = forward(params, toks[:, 16:17], cfg, cache=cache,
                             logits_mode="last")
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         set_mesh(mesh)
         p_sh, c_sh, _, _ = serve_shardings(mesh, cfg, 2, 32)
         params_s = jax.device_put(params, p_sh)

@@ -5,7 +5,7 @@ import pytest
 
 import jax.numpy as jnp
 
-from repro.kernels.instrumented_matmul import (matmul_traced,
+from repro.kernels.instrumented_matmul import (matmul_traced_pallas,
                                                matmul_traced_ref, BM, BN)
 import repro.core as pasta
 
@@ -15,7 +15,7 @@ import repro.core as pasta
 def test_traced_matmul_matches_oracle(rng, m, k, n):
     x = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
     w = jnp.asarray(rng.standard_normal((k, n)), jnp.float32)
-    out, trace = matmul_traced(x, w, interpret=True)
+    out, trace = matmul_traced_pallas(x, w, interpret=True)
     out_ref, trace_ref = matmul_traced_ref(x, w)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
                                rtol=1e-5, atol=1e-4)
@@ -27,7 +27,7 @@ def test_trace_buffer_flows_through_pasta(handler, rng):
     tools consume — never the raw records."""
     x = jnp.asarray(rng.standard_normal((256, 64)), jnp.float32)
     w = jnp.asarray(rng.standard_normal((64, 256)), jnp.float32)
-    out, trace = matmul_traced(x, w, interpret=True)
+    out, trace = matmul_traced_pallas(x, w, interpret=True)
     seen = []
     proc = pasta.EventProcessor(handler)
     handler.subscribe(lambda e: seen.append(e), kinds=("trace_buffer",))

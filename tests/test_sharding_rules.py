@@ -20,6 +20,7 @@ from repro.dist.collectives import dequantize_int8, quantize_int8
 from repro.dist.sharding import (DEFAULT_RULES, ShardingRules, get_mesh,
                                  get_rules, logical, mesh_axis_size,
                                  set_mesh, shard)
+from repro.launch.mesh import make_mesh
 from repro.models import cache_axes, param_axes
 
 # the activation-annotation names used by models.{layers,lm,moe,mamba2}
@@ -116,7 +117,7 @@ def test_shard_is_noop_without_mesh():
 
 
 def test_shard_rank_mismatch_is_tolerated_hint():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     set_mesh(mesh)
     x = jnp.ones((4, 8))
     assert shard(x, "batch") is x             # rank 1 hint on rank-2 tensor
@@ -125,7 +126,7 @@ def test_shard_rank_mismatch_is_tolerated_hint():
 
 
 def test_set_mesh_rules_override_and_reset():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     set_mesh(mesh, ShardingRules({**DEFAULT_RULES, "p_embed": None}))
     assert get_mesh() is mesh
     assert get_rules()["p_embed"] is None
@@ -142,7 +143,7 @@ def test_set_mesh_rules_override_and_reset():
 def test_mesh_axis_size_defaults_to_one():
     set_mesh(None)
     assert mesh_axis_size("data") == 1
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     set_mesh(mesh)
     assert mesh_axis_size("data") == 1
     assert mesh_axis_size("pod") == 1         # absent axis
