@@ -33,6 +33,7 @@ import time
 from typing import Callable, Iterable
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .annotate import GridIdFilter, current_region
 from . import events as events_mod
@@ -181,33 +182,34 @@ class EventHandler:
 
     # ------------------------------------------------------------- dispatch
     def _dispatch(self, batch: EventBatch) -> None:
-        for fn in tuple(self._batch_subs):
-            fn(batch)
-        if not self._subs:
-            return
-        if len(batch) == 1:
-            ev = batch.event(0)
-            for fn in self._subs.get(ev.kind.value, ()):
-                fn(ev)
-            for fn in self._subs.get("*", ()):
-                fn(ev)
-            return
-        star = self._subs.get("*", ())
-        if star:
-            idx = range(len(batch))
-        else:
-            wanted = [c for c in np.unique(batch.kinds)
-                      if self._subs.get(KIND_LIST[c].value)]
-            if not wanted:
+        with TraceAnnotation("pasta.dispatch"):
+            for fn in tuple(self._batch_subs):
+                fn(batch)
+            if not self._subs:
                 return
-            idx = np.nonzero(np.isin(batch.kinds, np.asarray(
-                wanted, dtype=np.int16)))[0]
-        for i in idx:
-            ev = batch.event(int(i))
-            for fn in self._subs.get(ev.kind.value, ()):
-                fn(ev)
-            for fn in star:
-                fn(ev)
+            if len(batch) == 1:
+                ev = batch.event(0)
+                for fn in self._subs.get(ev.kind.value, ()):
+                    fn(ev)
+                for fn in self._subs.get("*", ()):
+                    fn(ev)
+                return
+            star = self._subs.get("*", ())
+            if star:
+                idx = range(len(batch))
+            else:
+                wanted = [c for c in np.unique(batch.kinds)
+                          if self._subs.get(KIND_LIST[c].value)]
+                if not wanted:
+                    return
+                idx = np.nonzero(np.isin(batch.kinds, np.asarray(
+                    wanted, dtype=np.int16)))[0]
+            for i in idx:
+                ev = batch.event(int(i))
+                for fn in self._subs.get(ev.kind.value, ()):
+                    fn(ev)
+                for fn in star:
+                    fn(ev)
 
     # ------------------------------------------------- framework-side hooks
     def operator_start(self, name: str, **attrs) -> Event:
@@ -265,12 +267,9 @@ class EventHandler:
         """Walk a compiled executable (or HLO text) and emit kernel/collective
         events.  Returns the :class:`repro.core.hlo.HloStats` rollup."""
         text = compiled if isinstance(compiled, str) else compiled.as_text()
-        t0 = time.perf_counter()
         stats = hlo_mod.analyze_text(text, default_trip=default_trip)
-        parse_s = time.perf_counter() - t0
         self.emit(Event(EventKind.COMPILE, name=label,
-                        attrs={"parse_s": parse_s,
-                               "cost_analysis": cost_analysis or {}}))
+                        attrs={"cost_analysis": cost_analysis or {}}))
         for kname, count in stats.kernel_counts.items():
             gid = self._grid_id
             self._grid_id += 1

@@ -16,6 +16,15 @@ processor aggregates on device (Fig. 2b) or host (Fig. 2a baseline).
 Model code calls :func:`op_hook` at operator boundaries; it is a no-op under
 tracing (jit) and when no instrumenter is installed, so the hot path costs
 one global check.
+
+The fine-grained path times itself on the JAX profiler's clock with
+``jax.profiler.TraceAnnotation`` spans named ``pasta.*``: ``pasta.op`` (one
+operator boundary) holds ``pasta.op.records`` (record generation) and
+``pasta.op.objects`` (the sorted live-tensor table); ``pasta.dispatch``
+(``core/handler.py``) holds ``pasta.process.trace`` (``core/processor.py``),
+whose kernel calls open ``pasta.kernel.pad``, ``.launch`` and ``.fetch``
+(``kernels/ops.py``), and ``pasta.tool``; ``pasta.free`` is a tensor's
+release.  They record only while a profiler runs.
 """
 
 from __future__ import annotations
@@ -26,6 +35,7 @@ import weakref
 import numpy as np
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from .events import Event, EventKind
 from .pool import MemoryPool
@@ -73,34 +83,37 @@ class EagerInstrumenter:
         return h
 
     def _on_free(self, key) -> None:
-        h = self._tensors.pop(key, None)
-        if h is not None and h.live:
-            self.pool.free(h)
+        with TraceAnnotation("pasta.free"):
+            h = self._tensors.pop(key, None)
+            if h is not None and h.live:
+                self.pool.free(h)
 
     # ------------------------------------------------------------------ op
     def op(self, name: str, inputs, outputs) -> None:
-        handles = [self.tensor(a, f"{name}.in{i}")
-                   for i, a in enumerate(inputs)]
-        handles += [self.tensor(a, f"{name}.out{i}")
-                    for i, a in enumerate(outputs)]
-        tensors = [(h.addr, h.size) for h in handles]
-        self.handler.operator_start(name, tensors=tensors, traced=self.fine)
-        if self.fine:
-            self._emit_trace(name, handles)
-        self.handler.operator_end(name)
+        with TraceAnnotation("pasta.op"):
+            handles = [self.tensor(a, f"{name}.in{i}")
+                       for i, a in enumerate(inputs)]
+            handles += [self.tensor(a, f"{name}.out{i}")
+                        for i, a in enumerate(outputs)]
+            tensors = [(h.addr, h.size) for h in handles]
+            self.handler.operator_start(name, tensors=tensors,
+                                        traced=self.fine)
+            if self.fine:
+                self._emit_trace(name, handles)
+            self.handler.operator_end(name)
 
     def _emit_trace(self, name: str, handles) -> None:
-        recs = []
-        for h in handles:
-            n = max(1, min(h.size // self.stride,
-                           self.max_records // max(len(handles), 1)))
-            recs.append(h.addr + (np.arange(n, dtype=np.int64)
-                                  * self.stride) % h.size)
-        addrs = np.concatenate(recs)
+        per = self.max_records // max(len(handles), 1)
+        counts = [max(1, min(h.size // self.stride, per)) for h in handles]
+        with TraceAnnotation("pasta.op.records", records=sum(counts)):
+            addrs = np.concatenate([
+                h.addr + (np.arange(n, dtype=np.int64) * self.stride) % h.size
+                for h, n in zip(handles, counts)])
         # access-verified granularity = live TENSOR ranges (the paper's
         # object-to-access map at allocator granularity), NOT pool chunks —
         # this is exactly the tensor-vs-object distinction of §V-C1.
-        objs = sorted(t.addr_range() for t in self.pool.live_tensors())
+        with TraceAnnotation("pasta.op.objects"):
+            objs = sorted(t.addr_range() for t in self.pool.live_tensors())
         self.handler.trace_buffer(
             addrs, name=name, kernel=name, objects=objs,
             object_sizes=[e - s for s, e in objs],

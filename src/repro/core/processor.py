@@ -26,6 +26,7 @@ import bisect
 import time
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .events import (Event, EventBatch, EventKind, KIND_CODE, _SIGNED_CODES,
                      _SIGNED_SIZE_KINDS)
@@ -128,7 +129,8 @@ class EventProcessor:
                 self._preprocess_trace(ev)
             for tool in self.tools:
                 if tool.wants(ev.kind):
-                    tool.on_event(ev)
+                    with TraceAnnotation("pasta.tool"):
+                        tool.on_event(ev)
             return
         self.normalize_batch(batch)
         tmask = batch.kinds == _KC_TRACE
@@ -140,7 +142,8 @@ class EventProcessor:
         present = batch.present_kinds()
         for tool in self.tools:
             if any(tool.wants(k) for k in present):
-                tool.on_batch(batch)
+                with TraceAnnotation("pasta.tool"):
+                    tool.on_batch(batch)
 
     def _on_event(self, ev: Event) -> None:
         """Scalar compatibility shim — wraps a one-row batch."""
@@ -162,42 +165,43 @@ class EventProcessor:
         objects = ev.attrs.get("objects")
         if records is None:
             return
-        mode = "device" if self.device_analysis else "host"
-        elapsed = 0.0
-        hp = self.hotness
-        fusable = False
-        if objects is not None and hp is not None and mode == "device":
-            from repro.kernels import ops as kops
-            fusable = kops.can_fuse(len(objects), hp["n_blocks"],
-                                    hp["n_tbins"])
-        if fusable:
-            # fused path: per-object counts AND the hotness map in one
-            # device round-trip over the shared trace stream
-            t = ev.attrs.get("time", 0.0)
-            times = np.full(len(records), t)
-            counts, hot, elapsed = analyze_trace_fused(
-                records, times, objects, hp["base"], hp["n_blocks"],
-                hp["n_tbins"], hp["t_max"],
-                block_shift=hp.get("block_shift"))
-            ev.attrs["object_counts"] = counts
-            ev.attrs["hotness_map"] = hot
-        else:
-            if objects is not None:
-                counts, elapsed = analyze_access_trace(records, objects,
-                                                       mode=mode)
-                ev.attrs["object_counts"] = counts
-            if hp is not None:
+        with TraceAnnotation("pasta.process.trace"):
+            mode = "device" if self.device_analysis else "host"
+            elapsed = 0.0
+            hp = self.hotness
+            fusable = False
+            if objects is not None and hp is not None and mode == "device":
+                from repro.kernels import ops as kops
+                fusable = kops.can_fuse(len(objects), hp["n_blocks"],
+                                        hp["n_tbins"])
+            if fusable:
+                # fused path: per-object counts AND the hotness map in one
+                # device round-trip over the shared trace stream
                 t = ev.attrs.get("time", 0.0)
                 times = np.full(len(records), t)
-                hot, el2 = analyze_hotness_trace(
-                    records, times, hp["base"], hp["n_blocks"],
-                    hp["n_tbins"], hp["t_max"], mode=mode,
+                counts, hot, elapsed = analyze_trace_fused(
+                    records, times, objects, hp["base"], hp["n_blocks"],
+                    hp["n_tbins"], hp["t_max"],
                     block_shift=hp.get("block_shift"))
+                ev.attrs["object_counts"] = counts
                 ev.attrs["hotness_map"] = hot
-                elapsed += el2
-        ev.attrs["analysis_s"] = elapsed
-        ev.attrs["analysis_mode"] = mode
-        ev.attrs.pop("records", None)   # aggregates only past this point
+            else:
+                if objects is not None:
+                    counts, elapsed = analyze_access_trace(records, objects,
+                                                           mode=mode)
+                    ev.attrs["object_counts"] = counts
+                if hp is not None:
+                    t = ev.attrs.get("time", 0.0)
+                    times = np.full(len(records), t)
+                    hot, el2 = analyze_hotness_trace(
+                        records, times, hp["base"], hp["n_blocks"],
+                        hp["n_tbins"], hp["t_max"], mode=mode,
+                        block_shift=hp.get("block_shift"))
+                    ev.attrs["hotness_map"] = hot
+                    elapsed += el2
+            ev.attrs["analysis_s"] = elapsed
+            ev.attrs["analysis_mode"] = mode
+            ev.attrs.pop("records", None)   # aggregates only past this point
 
 
 # ---------------------------------------------------------------------------

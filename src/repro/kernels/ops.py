@@ -12,6 +12,11 @@ Dispatch policy (:func:`backend`):
 
 Addresses are byte int64 at the API; kernels work in 512-byte units (int32),
 which is lossless because the pool rounds tensors to 512 B.
+
+Each reduction call opens three profiler spans on every backend:
+``pasta.kernel.pad`` (unit conversion and padding; stats ``records`` and
+``padded``), ``pasta.kernel.launch`` (the transfers and the jitted call) and
+``pasta.kernel.fetch`` (the read-back, which waits for the device).
 """
 
 from __future__ import annotations
@@ -21,6 +26,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import ref
 from .trace_aggregate import BLOCK_T as AGG_BLOCK_T, BLOCK_K as AGG_BLOCK_K
@@ -74,24 +80,38 @@ def _to_units(addrs_bytes) -> np.ndarray:
     return a.astype(np.int32)
 
 
+def _pad_span(n: int, tile: int, be: str) -> TraceAnnotation:
+    """The span around a kernel call's unit conversion and padding, with
+    its record count before and after padding (equal on the ``ref``
+    backend, which pads nothing)."""
+    return TraceAnnotation("pasta.kernel.pad", records=n,
+                           padded=n if be == "ref" else _padded(n, tile))
+
+
 def object_histogram(addrs_bytes, starts_bytes, ends_bytes):
     """Per-object access counts. Returns int64[K]."""
     k = len(starts_bytes)
-    a = _to_units(addrs_bytes)
-    s = _to_units(starts_bytes)
-    e = _to_units(ends_bytes)
-    assert a.shape[0] < 2**24, "split traces >16M records for exact f32 accum"
     be = backend()
-    if be == "ref":
-        return np.asarray(_ref_object_histogram(
-            jnp.asarray(a), jnp.asarray(s), jnp.asarray(e))).astype(np.int64)
-    a = _pad_to(a, AGG_BLOCK_T, -1)
-    s = _pad_to(s, AGG_BLOCK_K, _I32_MAX)
-    e = _pad_to(e, AGG_BLOCK_K, _I32_MAX)
-    counts = object_histogram_pallas(jnp.asarray(a), jnp.asarray(s),
-                                     jnp.asarray(e),
-                                     interpret=be == "interpret")
-    return np.asarray(counts[:k]).astype(np.int64)
+    with _pad_span(len(addrs_bytes), AGG_BLOCK_T, be):
+        a = _to_units(addrs_bytes)
+        s = _to_units(starts_bytes)
+        e = _to_units(ends_bytes)
+        assert a.shape[0] < 2**24, \
+            "split traces >16M records for exact f32 accum"
+        if be != "ref":
+            a = _pad_to(a, AGG_BLOCK_T, -1)
+            s = _pad_to(s, AGG_BLOCK_K, _I32_MAX)
+            e = _pad_to(e, AGG_BLOCK_K, _I32_MAX)
+    with TraceAnnotation("pasta.kernel.launch"):
+        if be == "ref":
+            counts = _ref_object_histogram(jnp.asarray(a), jnp.asarray(s),
+                                           jnp.asarray(e))
+        else:
+            counts = object_histogram_pallas(
+                jnp.asarray(a), jnp.asarray(s), jnp.asarray(e),
+                interpret=be == "interpret")[:k]
+    with TraceAnnotation("pasta.kernel.fetch"):
+        return np.asarray(counts).astype(np.int64)
 
 
 def hotness_histogram(addrs_bytes, times, base_addr: int, n_blocks: int,
@@ -99,24 +119,28 @@ def hotness_histogram(addrs_bytes, times, base_addr: int, n_blocks: int,
                       block_shift: int = BLOCK_SHIFT):
     """[time-bin × block] hotness (block = 2^block_shift 512-B units; default
     2 MiB, the UVM page-group size). Returns int64[n_tbins, n_blocks]."""
-    a = _to_units(addrs_bytes)
-    t = np.asarray(times, dtype=np.float64)
-    tb = np.minimum((t / max(t_max, 1e-12) * n_tbins).astype(np.int32),
-                    n_tbins - 1)
-    base = np.int32(int(base_addr) >> UNIT_SHIFT)
     be = backend()
-    if be == "ref":
-        out = _ref_hotness(jnp.asarray(a), jnp.asarray(tb), base,
-                           n_blocks=n_blocks, n_tbins=n_tbins,
-                           block_shift=block_shift)
+    with _pad_span(len(addrs_bytes), HOT_BLOCK_T, be):
+        a = _to_units(addrs_bytes)
+        t = np.asarray(times, dtype=np.float64)
+        tb = np.minimum((t / max(t_max, 1e-12) * n_tbins).astype(np.int32),
+                        n_tbins - 1)
+        base = np.int32(int(base_addr) >> UNIT_SHIFT)
+        if be != "ref":
+            a = _pad_to(a, HOT_BLOCK_T, -1)
+            tb = _pad_to(tb, HOT_BLOCK_T, -1)
+    with TraceAnnotation("pasta.kernel.launch"):
+        if be == "ref":
+            out = _ref_hotness(jnp.asarray(a), jnp.asarray(tb), base,
+                               n_blocks=n_blocks, n_tbins=n_tbins,
+                               block_shift=block_shift)
+        else:
+            nb_p = n_blocks + ((-n_blocks) % HOT_BLOCK_B)
+            out = hotness_histogram_pallas(
+                jnp.asarray(a), jnp.asarray(tb), base, nb_p, n_tbins,
+                block_shift, interpret=be == "interpret")[:, :n_blocks]
+    with TraceAnnotation("pasta.kernel.fetch"):
         return np.asarray(out).astype(np.int64)
-    a_p = _pad_to(a, HOT_BLOCK_T, -1)
-    tb_p = _pad_to(tb, HOT_BLOCK_T, -1)
-    nb_p = n_blocks + ((-n_blocks) % HOT_BLOCK_B)
-    out = hotness_histogram_pallas(jnp.asarray(a_p), jnp.asarray(tb_p), base,
-                                   nb_p, n_tbins, block_shift,
-                                   interpret=be == "interpret")
-    return np.asarray(out[:, :n_blocks]).astype(np.int64)
 
 
 def can_fuse(n_objects: int, n_blocks: int, n_tbins: int) -> bool:
@@ -142,32 +166,38 @@ def trace_aggregate(addrs_bytes, times, starts_bytes, ends_bytes,
     running :func:`object_histogram` and :func:`hotness_histogram`
     separately."""
     k = len(starts_bytes)
-    a = _to_units(addrs_bytes)
-    s = _to_units(starts_bytes)
-    e = _to_units(ends_bytes)
-    t = np.asarray(times, dtype=np.float64)
-    tb = np.minimum((t / max(t_max, 1e-12) * n_tbins).astype(np.int32),
-                    n_tbins - 1)
-    base = np.int32(int(base_addr) >> UNIT_SHIFT)
-    assert a.shape[0] < 2**24, "split traces >16M records for exact f32 accum"
     be = backend()
-    if be == "ref":
-        counts, hist = _ref_trace_aggregate(
-            jnp.asarray(a), jnp.asarray(tb), jnp.asarray(s), jnp.asarray(e),
-            base, n_blocks=n_blocks, n_tbins=n_tbins, block_shift=block_shift)
+    with _pad_span(len(addrs_bytes), FUSE_BLOCK_T, be):
+        a = _to_units(addrs_bytes)
+        s = _to_units(starts_bytes)
+        e = _to_units(ends_bytes)
+        t = np.asarray(times, dtype=np.float64)
+        tb = np.minimum((t / max(t_max, 1e-12) * n_tbins).astype(np.int32),
+                        n_tbins - 1)
+        base = np.int32(int(base_addr) >> UNIT_SHIFT)
+        assert a.shape[0] < 2**24, \
+            "split traces >16M records for exact f32 accum"
+        if be != "ref":
+            a = _pad_to(a, FUSE_BLOCK_T, -1)
+            tb = _pad_to(tb, FUSE_BLOCK_T, -1)
+            s = _pad_to(s, FUSE_BLOCK_K, _I32_MAX)
+            e = _pad_to(e, FUSE_BLOCK_K, _I32_MAX)
+    with TraceAnnotation("pasta.kernel.launch"):
+        if be == "ref":
+            counts, hist = _ref_trace_aggregate(
+                jnp.asarray(a), jnp.asarray(tb), jnp.asarray(s),
+                jnp.asarray(e), base, n_blocks=n_blocks, n_tbins=n_tbins,
+                block_shift=block_shift)
+        else:
+            nb_p = n_blocks + ((-n_blocks) % HOT_BLOCK_B)
+            counts, hist = trace_aggregate_pallas(
+                jnp.asarray(a), jnp.asarray(tb), jnp.asarray(s),
+                jnp.asarray(e), base, block_shift, n_blocks=nb_p,
+                n_tbins=n_tbins, interpret=be == "interpret")
+            counts, hist = counts[:k], hist[:, :n_blocks]
+    with TraceAnnotation("pasta.kernel.fetch"):
         return (np.asarray(counts).astype(np.int64),
                 np.asarray(hist).astype(np.int64))
-    a_p = _pad_to(a, FUSE_BLOCK_T, -1)
-    tb_p = _pad_to(tb, FUSE_BLOCK_T, -1)
-    s_p = _pad_to(s, FUSE_BLOCK_K, _I32_MAX)
-    e_p = _pad_to(e, FUSE_BLOCK_K, _I32_MAX)
-    nb_p = n_blocks + ((-n_blocks) % HOT_BLOCK_B)
-    counts, hist = trace_aggregate_pallas(
-        jnp.asarray(a_p), jnp.asarray(tb_p), jnp.asarray(s_p),
-        jnp.asarray(e_p), base, block_shift, n_blocks=nb_p, n_tbins=n_tbins,
-        interpret=be == "interpret")
-    return (np.asarray(counts[:k]).astype(np.int64),
-            np.asarray(hist[:, :n_blocks]).astype(np.int64))
 
 
 def matmul_traced(x: jax.Array, w: jax.Array):
