@@ -10,8 +10,9 @@ Two trace-analysis execution models, mirroring the paper's Fig. 2:
     *where they were produced* by vectorized device code — the Pallas TPU
     kernels in :mod:`repro.kernels` (with an XLA fallback off-TPU) — and only
     O(#objects) aggregates are transferred.  When both per-object counts and
-    the hotness map are requested, the fused ``trace_aggregate`` kernel
-    produces both in a single stream over the trace (one device round-trip).
+    the hotness map are requested, ``ops.trace_aggregate`` produces both with
+    one transfer call and one device round-trip: the fused kernel where its
+    working set fits the VMEM budget, else the two tiled programs.
 
 The coarse-grained tier is columnar end-to-end: the processor subscribes a
 *batch* callback, ``normalize_batch`` fixes cross-backend inconsistencies
@@ -169,14 +170,9 @@ class EventProcessor:
             mode = "device" if self.device_analysis else "host"
             elapsed = 0.0
             hp = self.hotness
-            fusable = False
             if objects is not None and hp is not None and mode == "device":
-                from repro.kernels import ops as kops
-                fusable = kops.can_fuse(len(objects), hp["n_blocks"],
-                                        hp["n_tbins"])
-            if fusable:
-                # fused path: per-object counts AND the hotness map in one
-                # device round-trip over the shared trace stream
+                # per-object counts AND the hotness map: one transfer call
+                # and one device round-trip over the shared trace
                 t = ev.attrs.get("time", 0.0)
                 times = np.full(len(records), t)
                 counts, hot, elapsed = analyze_trace_fused(
@@ -268,10 +264,11 @@ def analyze_hotness_trace(addrs, times, base_addr: int, n_blocks: int,
 def analyze_trace_fused(addrs, times, objects, base_addr: int, n_blocks: int,
                         n_tbins: int, t_max: float,
                         block_shift: int | None = None):
-    """Fused device-resident reduction: per-object counts and the
-    [time_bin, block] hotness map from ONE pass over the trace (the
-    ``trace_aggregate`` kernel — shared addr tiles, two accumulators).
-    Returns ``(counts, hotness, elapsed_seconds)``."""
+    """Device-resident reduction of both aggregates: per-object counts and
+    the [time_bin, block] hotness map through ``ops.trace_aggregate`` (one
+    transfer call, one read-back; the fused kernel or the two tiled
+    programs, by its VMEM rule).  Returns ``(counts, hotness,
+    elapsed_seconds)``."""
     from repro.kernels import ops as kops
     if block_shift is None:
         block_shift = kops.BLOCK_SHIFT

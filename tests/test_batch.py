@@ -302,6 +302,93 @@ def test_fused_fallback_beyond_vmem_ceilings(handler, rng):
         os.environ["REPRO_PALLAS_INTERPRET"] = "0"
 
 
+#: the fine-grained profile's hotness shape, which the fused kernel's VMEM
+#: budget refuses at any object count: 1,048 blocks in 8 time bins
+WIDE = dict(k=128, n_blocks=1048, n_tbins=8)
+
+
+def _numpy_aggregates(addrs, times, starts, ends, base, n_blocks, n_tbins):
+    """Plain numpy reduction: per-object counts over the half-open ranges
+    and the [time bin, 2 MiB block] map (time axis over [0, 1))."""
+    a = np.asarray(addrs, np.int64)
+    idx = np.searchsorted(starts, a, side="right") - 1
+    hit = (idx >= 0) & (a < ends[np.maximum(idx, 0)])
+    counts = np.bincount(idx[hit], minlength=len(starts))
+    tb = np.minimum((np.asarray(times) * n_tbins).astype(np.int64),
+                    n_tbins - 1)
+    blk = (a - base) // (512 << 12)
+    ok = (blk >= 0) & (blk < n_blocks)
+    hot = np.zeros((n_tbins, n_blocks), np.int64)
+    np.add.at(hot, (tb[ok], blk[ok]), 1)
+    return counts, hot
+
+
+@pytest.mark.parametrize("n", [1, 1024, 1025, 2048, 2049, 4096])
+def test_two_program_aggregate_matches_separate_and_numpy(rng, n,
+                                                          monkeypatch):
+    """Where the fused kernel's VMEM budget refuses the problem,
+    ``trace_aggregate`` runs the two tiled programs on one transfer's
+    inputs; at each padding edge (up to 1,024 records the two kernels pad
+    the addresses to different lengths) it equals the separate calls and
+    numpy, exactly."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    from repro.kernels import ops
+    assert not ops.can_fuse(WIDE["k"], WIDE["n_blocks"], WIDE["n_tbins"])
+    assert (ops._padded(n, ops.AGG_BLOCK_T)
+            == ops._padded(n, ops.HOT_BLOCK_T)) == (n > 1024)
+    addrs, times, starts, ends = _mk_trace(rng, k=WIDE["k"], n=n)
+    base = 2 << 20
+    nb, tb = WIDE["n_blocks"], WIDE["n_tbins"]
+    counts, hot = ops.trace_aggregate(addrs, times, starts, ends, base, nb,
+                                      tb, 1.0)
+    np.testing.assert_array_equal(counts,
+                                  ops.object_histogram(addrs, starts, ends))
+    np.testing.assert_array_equal(
+        hot, ops.hotness_histogram(addrs, times, base, nb, tb, 1.0))
+    want_counts, want_hot = _numpy_aggregates(addrs, times, starts, ends,
+                                              base, nb, tb)
+    np.testing.assert_array_equal(counts, want_counts)
+    np.testing.assert_array_equal(hot, want_hot)
+    assert counts.dtype == hot.dtype == np.int64
+    assert counts.shape == (WIDE["k"],) and hot.shape == (tb, nb)
+
+
+def test_processor_routes_refused_fusion_through_trace_aggregate(
+        handler, rng, monkeypatch):
+    """A buffer the fused kernel cannot host still goes through
+    ``ops.trace_aggregate``, the seam a planted fault in the per-object
+    counts wraps, and never through the separate calls."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    from repro.kernels import ops
+    calls = []
+
+    def spy(name, fn):
+        def wrapped(*a, **kw):
+            calls.append(name)
+            return fn(*a, **kw)
+        monkeypatch.setattr(ops, name, wrapped)
+    for name in ("trace_aggregate", "object_histogram", "hotness_histogram"):
+        spy(name, getattr(ops, name))
+    addrs, _times, starts, ends = _mk_trace(rng, k=WIDE["k"], n=3000)
+    objs = list(zip(starts, ends))
+    hp = {"base": 2 << 20, "n_blocks": WIDE["n_blocks"],
+          "n_tbins": WIDE["n_tbins"], "t_max": 1.0}
+    assert not ops.can_fuse(len(objs), hp["n_blocks"], hp["n_tbins"])
+    seen = []
+    proc = pasta.EventProcessor(handler, hotness=hp)
+    handler.subscribe(lambda e: seen.append(e), kinds=("trace_buffer",))
+    handler.trace_buffer(addrs, name="k", objects=objs,
+                         object_sizes=[e - s for s, e in objs], time=0.5)
+    proc.close()
+    assert calls == ["trace_aggregate"]
+    want_counts, want_hot = _numpy_aggregates(
+        addrs, np.full(len(addrs), 0.5), starts, ends, hp["base"],
+        hp["n_blocks"], hp["n_tbins"])
+    np.testing.assert_array_equal(seen[0].attrs["object_counts"],
+                                  want_counts)
+    np.testing.assert_array_equal(seen[0].attrs["hotness_map"], want_hot)
+
+
 def test_processor_fused_single_pass_matches_two_pass(handler, rng):
     """The processor's fused device path must attach the same aggregates as
     the two-kernel path (hotness disabled → separate; enabled → fused)."""

@@ -184,6 +184,69 @@ def test_kernel_call_spans_on_every_backend(kernel, backend, tmp_path,
         np.testing.assert_array_equal(w, g)
 
 
+#: a hotness shape the fused kernel's VMEM budget refuses (the fine-grained
+#: profile's own): ``trace_aggregate`` runs its two tiled programs
+WIDE_BLOCKS, WIDE_TBINS = 1048, 8
+
+
+@pytest.mark.parametrize("n", [1000, 4096])
+def test_two_program_aggregate_spans(n, tmp_path, monkeypatch):
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    assert not ops.can_fuse(2, WIDE_BLOCKS, WIDE_TBINS)
+    rng = np.random.default_rng(2)
+    addrs = rng.integers(0, 2 << 20, n)
+    times = rng.random(n)
+
+    def call():
+        return ops.trace_aggregate(addrs, times, [0, 1 << 20],
+                                   [1 << 19, 2 << 20], 0, WIDE_BLOCKS,
+                                   WIDE_TBINS, 1.0)
+    want = call()                            # compile outside the profile
+    got = []
+    events = _profiled(lambda: got.append(call()), tmp_path)
+    assert [e[0] for e in events] == ["pasta.kernel.pad",
+                                      "pasta.kernel.launch",
+                                      "pasta.kernel.fetch"]
+    assert all(a[2] <= b[1] for a, b in zip(events, events[1:]))
+    # the padded length is the mean over the two programs' padding rules
+    padded = (ops._padded(n, ops.AGG_BLOCK_T)
+              + ops._padded(n, ops.HOT_BLOCK_T)) // 2
+    assert events[0][3] == {"records": n, "padded": padded}
+    assert events[1][3] == {"transfers": 1, "programs": 2}
+    for w, g in zip(want, got[0]):
+        np.testing.assert_array_equal(w, g)
+
+
+def test_refused_fusion_reads_each_buffer_back_once(tmp_path, monkeypatch):
+    """A buffer the fused kernel cannot host costs the processor one
+    blocking read-back, as a fused one does."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    hp = {"base": 0, "n_blocks": WIDE_BLOCKS, "n_tbins": WIDE_TBINS,
+          "t_max": 1.0}
+    objects = [(0, 1 << 19), (1 << 20, 2 << 20)]
+    assert not ops.can_fuse(len(objects), hp["n_blocks"], hp["n_tbins"])
+    handler = pasta.EventHandler()
+    proc = pasta.EventProcessor(handler, hotness=hp)
+    rng = np.random.default_rng(3)
+
+    def emit(n_buffers):
+        for i in range(n_buffers):
+            handler.trace_buffer(rng.integers(0, 2 << 20, 3000), name="k",
+                                 objects=objects,
+                                 object_sizes=[1 << 19, 1 << 20],
+                                 time=i / n_buffers)
+    emit(1)                                  # compile outside the profile
+    events = _profiled(lambda: emit(3), tmp_path)
+    proc.close()
+    count = {n: sum(e[0] == n for e in events)
+             for n in ("pasta.process.trace", "pasta.kernel.launch",
+                       "pasta.kernel.fetch")}
+    assert count == {"pasta.process.trace": 3, "pasta.kernel.launch": 3,
+                     "pasta.kernel.fetch": 3}
+    assert all(e[3] == {"transfers": 1, "programs": 2}
+               for e in events if e[0] == "pasta.kernel.launch")
+
+
 def test_no_span_is_recorded_without_a_profiler(tmp_path):
     """Spans opened before a profiler starts leave nothing in its trace."""
     def idle():
