@@ -22,6 +22,7 @@ _ARCHS = {
     "musicgen-large": "musicgen_large",
     "paper-gpt2": "paper_gpt2",
     "paper-bert": "paper_bert",
+    "lfm2-8b-a1b": "lfm2_8b_a1b",
 }
 
 ASSIGNED = list(_ARCHS)[:10]          # the 10 assigned architectures
@@ -39,7 +40,9 @@ def list_archs() -> list:
 
 
 def reduced(cfg: ModelConfig, seq_len: int = 64) -> ModelConfig:
-    """Family-preserving tiny variant for CPU smoke tests."""
+    """Family-preserving tiny variant for CPU smoke tests (a conv_moe model
+    keeps its first six layers, with both operator kinds, and the share of
+    its experts held)."""
     hd = 16
     n_heads = 4 if cfg.n_heads else 0
     if cfg.n_heads:
@@ -47,8 +50,12 @@ def reduced(cfg: ModelConfig, seq_len: int = 64) -> ModelConfig:
         n_kv = max(1, n_heads // min(ratio, n_heads))
     else:
         n_kv = 0
+    n_layers = {"hybrid": 5, "conv_moe": 6}.get(cfg.family, 2)
+    n_experts = 8 if cfg.family == "conv_moe" else 4
+    held = cfg.experts_held * n_experts // cfg.n_experts \
+        if cfg.n_experts_held else 0
     updates = dict(
-        n_layers=5 if cfg.family == "hybrid" else 2,
+        n_layers=n_layers,
         d_model=64,
         n_heads=n_heads, n_kv_heads=n_kv,
         head_dim=hd if cfg.n_heads else 0,
@@ -58,12 +65,13 @@ def reduced(cfg: ModelConfig, seq_len: int = 64) -> ModelConfig:
         ssm_head_dim=8 if cfg.ssm_state else 64,
         ssm_chunk=16,
         shared_attn_every=2 if cfg.family == "hybrid" else 0,
-        n_experts=4 if cfg.n_experts else 0,
+        n_experts=n_experts if cfg.n_experts else 0,
         n_experts_active=2 if cfg.n_experts else 0,
+        n_experts_held=held,
         d_ff_expert=32 if cfg.n_experts else 0,
-        capacity_factor=2.0,        # = e/k: dropless at smoke scale, so
-                                    # teacher-forced == decode exactly
         n_shared_experts=min(cfg.n_shared_experts, 1),
+        layer_pattern=cfg.layer_pattern[:n_layers],
+        n_dense_layers=min(cfg.n_dense_layers, n_layers),
         param_dtype="float32", opt_moment_dtype=cfg.opt_moment_dtype,
         dtype="float32",
     )

@@ -255,7 +255,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     mf = RL.model_flops(cfg.n_params, n_tokens,
                         training=shape.kind == "train",
                         n_active_params=cfg.n_active_params
-                        if cfg.family == "moe" else None)
+                        if cfg.family in ("moe", "conv_moe") else None)
     # the analytic model's target chip is named, not inherited
     rl = RL.roofline(stats.flops, stats.hbm_bytes,
                      stats.total_collective_bytes,

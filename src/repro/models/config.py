@@ -4,11 +4,15 @@ from __future__ import annotations
 
 import dataclasses
 
+#: the gated short convolution's causal taps (LFM2's ``conv_L_cache``)
+CONV_TAPS = 3
+
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str                  # dense | moe | ssm | hybrid | vlm | audio
+                                 # | conv_moe
     n_layers: int
     d_model: int
     n_heads: int = 0
@@ -31,7 +35,14 @@ class ModelConfig:
     n_experts_active: int = 0
     d_ff_expert: int = 0
     n_shared_experts: int = 0
-    capacity_factor: float = 1.25
+    router: str = "softmax"      # softmax | sigmoid_bias (selection by
+                                 # sigmoid + expert bias, gates by sigmoid)
+    n_experts_held: int = 0      # experts [0, held) live here; 0 = all
+
+    # per-layer operator pattern (conv_moe): "attn" | "conv" per layer; the
+    # first ``n_dense_layers`` take the dense MLP, the rest the experts
+    layer_pattern: tuple = ()
+    n_dense_layers: int = 0
 
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0
@@ -68,6 +79,10 @@ class ModelConfig:
     # attention-free archs can run 0.5M-token shapes; full-attention skip
     supports_long_context: bool = False
 
+    def __post_init__(self):
+        # a pattern read from JSON arrives as a list
+        object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
+
     # ---------------------------------------------------------------- derived
     @property
     def kv_dim(self) -> int:
@@ -85,7 +100,34 @@ class ModelConfig:
     def ssm_heads(self) -> int:
         return self.d_inner // self.ssm_head_dim
 
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
+
+    def layer_period(self) -> tuple:
+        """(length, repeats) of the repeating unit of ``layer_pattern``
+        after the dense layers: of the units that repeat at least twice in
+        a row from the start, the one that covers most layers (the shortest
+        on a tie); where none repeats, all of them, once.  Layers past the
+        last whole repeat run unstacked."""
+        rest = self.layer_pattern[self.n_dense_layers:]
+
+        def repeats(p):
+            n = 0
+            while (n + 1) * p <= len(rest) and \
+                    rest[n * p:(n + 1) * p] == rest[:p]:
+                n += 1
+            return n
+        best, cover = (len(rest), 1 if rest else 0), 0
+        for p in range(1, len(rest) // 2 + 1):
+            n = repeats(p)
+            if n >= 2 and n * p > cover:
+                best, cover = (p, n), n * p
+        return best
+
     def is_attn_layer(self, i: int) -> bool:
+        if self.layer_pattern:
+            return self.layer_pattern[i] == "attn"
         if self.family in ("ssm",):
             return False
         if self.family == "hybrid":
@@ -108,19 +150,37 @@ class ModelConfig:
                 + 2 * self.d_model * self.kv_dim   # Wk, Wv
                 + self.q_dim * self.d_model)       # Wo
 
-    def mlp_params_per_layer(self) -> int:
-        if self.family in ("moe",) and self.n_experts:
+    def _router_params(self) -> int:
+        bias = self.n_experts if self.router == "sigmoid_bias" else 0
+        return self.d_model * self.n_experts + bias
+
+    def _has_experts(self, i: int) -> bool:
+        """Whether layer ``i`` (from the end where negative) has experts."""
+        return self.family in ("moe", "conv_moe") and self.n_experts > 0 \
+            and i % self.n_layers >= self.n_dense_layers
+
+    def mlp_params_per_layer(self, i: int = -1) -> int:
+        if self._has_experts(i):
             per_e = 3 * self.d_model * self.d_ff_expert
-            return (self.n_experts + self.n_shared_experts) * per_e \
-                + self.d_model * self.n_experts        # router
+            return (self.experts_held + self.n_shared_experts) * per_e \
+                + self._router_params()
         return 3 * self.d_ff * self.d_model            # swiglu/geglu
 
-    def mlp_active_params_per_layer(self) -> int:
-        if self.family in ("moe",) and self.n_experts:
+    def mlp_active_params_per_layer(self, i: int = -1) -> int:
+        if self._has_experts(i):
             per_e = 3 * self.d_model * self.d_ff_expert
             return (self.n_experts_active + self.n_shared_experts) * per_e \
-                + self.d_model * self.n_experts
-        return self.mlp_params_per_layer()
+                + self._router_params()
+        return self.mlp_params_per_layer(i)
+
+    def conv_params_per_layer(self) -> int:
+        d = self.d_model
+        return d * 3 * d + CONV_TAPS * d + d * d  # w_in, conv, w_out
+
+    def op_params_per_layer(self, i: int) -> int:
+        if self.is_attn_layer(i):
+            return self.attn_params_per_layer()
+        return self.conv_params_per_layer()
 
     def ssm_params_per_layer(self) -> int:
         di, ds, nh = self.d_inner, self.ssm_state, self.ssm_heads
@@ -135,7 +195,7 @@ class ModelConfig:
             return self.ssm_params_per_layer()
         if self.family == "hybrid":
             return self.ssm_params_per_layer()         # shared attn counted once
-        return self.attn_params_per_layer() + self.mlp_params_per_layer()
+        return self.op_params_per_layer(i) + self.mlp_params_per_layer(i)
 
     @property
     def n_params(self) -> int:
@@ -148,9 +208,10 @@ class ModelConfig:
 
     @property
     def n_active_params(self) -> int:
-        if self.family != "moe":
+        if self.family not in ("moe", "conv_moe"):
             return self.n_params
         total = self.embed_params()
-        total += self.n_layers * (self.attn_params_per_layer()
-                                  + self.mlp_active_params_per_layer())
+        total += sum(self.op_params_per_layer(i)
+                     + self.mlp_active_params_per_layer(i)
+                     for i in range(self.n_layers))
         return total

@@ -1,11 +1,13 @@
-"""Shared transformer layers: RMSNorm, RoPE/M-RoPE, GQA attention, GLU MLPs.
+"""Shared transformer layers: RMSNorm, RoPE/M-RoPE, GQA attention, GLU MLPs,
+and the gated short convolution of the conv/attention hybrids (LFM2).
 
 Numerics: matmuls in the config compute dtype (bf16), softmax/norm statistics
 in f32.  Attention paths:
 
   * ``dense``   — full (S×T) scores; training and short prefill.
   * ``blocked`` — lax.scan over KV chunks with online softmax (flash-style);
-    long prefill where S² scores would not fit.
+    long sequences where S² scores would not fit.  The scan body is
+    rematerialized, so the backward pass keeps only the per-chunk carries.
   * ``decode``  — one (or few) query tokens against a KV cache whose sequence
     dim may be sharded over the ``model`` mesh axis; softmax statistics
     reduce globally (GSPMD inserts the small all-reduces), which is the
@@ -22,7 +24,7 @@ import jax.numpy as jnp
 
 from repro.core.instrument import op_hook
 from repro.dist.sharding import shard
-from .config import ModelConfig
+from .config import CONV_TAPS, ModelConfig
 
 NEG_INF = -1e30
 
@@ -179,7 +181,8 @@ def _sdpa_blocked(q, k, v, causal: bool, chunk: int = 1024):
     acc0 = jnp.zeros((b, hkv, g, s, d_), jnp.float32)
     m0 = jnp.full((b, hkv, g, s), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, hkv, g, s), jnp.float32)
-    (acc, m, l, _), _ = jax.lax.scan(body, (acc0, m0, l0, 0), (k_c, v_c))
+    (acc, m, l, _), _ = jax.lax.scan(jax.checkpoint(body),
+                                     (acc0, m0, l0, 0), (k_c, v_c))
     out = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
     out = out.transpose(0, 3, 1, 2, 4)                       # (B,S,Hkv,G,D)
     return out.reshape(b, s, hkv * g, d_)
@@ -358,4 +361,43 @@ def mlp(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     y = jnp.einsum("bsf,fd->bsd", h, p["w_down"].astype(dt))
     y = shard(y, "batch", "seq", "embed")
     op_hook("mlp.glu", (x, p["w_gate"], p["w_up"], p["w_down"]), (g, u, y))
+    return y
+
+
+# ------------------------------------------------------ gated short conv
+def init_short_conv(key, cfg: ModelConfig, dtype) -> dict:
+    d = cfg.d_model
+    k1, k2, k3 = jax.random.split(key, 3)
+    return {
+        "w_in": jax.random.normal(k1, (d, 3 * d), dtype) / math.sqrt(d),
+        "conv": jax.random.normal(k2, (CONV_TAPS, d), dtype)
+        / math.sqrt(CONV_TAPS),
+        "w_out": jax.random.normal(k3, (d, d), dtype) / math.sqrt(d),
+    }
+
+
+def short_conv_param_axes() -> dict:
+    return {"w_in": ("p_embed", None), "conv": (None, None),
+            "w_out": (None, "p_embed")}
+
+
+def short_conv(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """LFM2's gated short convolution over (B, S, d):
+    ``[B, C, x~] = split3(x W_in)``, ``u = B * x~``, ``c_t = sum_j w_j * u_(t -
+    L + 1 + j)`` (depthwise, causal, zero before the first token), ``y = (C
+    * c) W_out``.  The taps accumulate in float32."""
+    dt = x.dtype
+    with jax.named_scope("conv.short"):
+        bcx = jnp.einsum("bsd,de->bse", x, p["w_in"].astype(dt))
+        gate_b, gate_c, xt = jnp.split(bcx, 3, axis=-1)
+        u = (gate_b * xt).astype(jnp.float32)
+        taps = p["conv"].astype(jnp.float32)
+        width, s = taps.shape[0], x.shape[1]
+        up = jnp.pad(u, ((0, 0), (width - 1, 0), (0, 0)))
+        c = sum(up[:, j:j + s] * taps[j] for j in range(width))
+        y = jnp.einsum("bsd,de->bse",
+                       (gate_c.astype(jnp.float32) * c).astype(dt),
+                       p["w_out"].astype(dt))
+        y = shard(y, "batch", "seq", "embed")
+    op_hook("conv.short", (x, p["w_in"], p["conv"], p["w_out"]), (y,))
     return y

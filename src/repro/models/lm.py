@@ -2,12 +2,19 @@
 
   * dense / vlm / audio — GQA attention + GLU MLP blocks (vlm/audio differ
     only in the stubbed modality frontend and M-RoPE);
-  * moe   — attention + sort-based capacity MoE blocks;
+  * moe   — attention + dropless MoE blocks (grouped matmuls over the
+    experts held);
   * ssm   — Mamba2 (SSD) blocks, attention-free;
   * hybrid — Mamba2 backbone with ONE weight-shared transformer block applied
     after every ``shared_attn_every`` SSM layers (Zamba2): layers are scanned
     in (group, layer-in-group) shape so each shared-attention application has
-    its own KV-cache slot while the block weights stay shared.
+    its own KV-cache slot while the block weights stay shared;
+  * conv_moe — a per-layer pattern of gated short convolutions and GQA
+    attention (LFM2): ``n_dense_layers`` leading layers with the dense MLP
+    (``prefix``), then whole repeats of the pattern's period scanned with
+    each position's weights stacked (``period``), then any layers left over
+    (``suffix``); every layer after the dense ones takes the experts.
+    Training only: the convolution's state is not cached for serving.
 
 All layer stacks run under ``jax.lax.scan`` (compact HLO, fast compiles at
 512 devices) with configurable remat.  Instrumented eager execution for the
@@ -77,6 +84,8 @@ def init_params(key, cfg: ModelConfig) -> dict:
             return {"ln": jnp.zeros((cfg.d_model,), dt),
                     "mamba": M.init_mamba2(k, cfg, dt)}
         p["layers"] = _stack_init(one, keys[2], cfg.n_layers)
+    elif cfg.family == "conv_moe":
+        p.update(_init_pattern(keys[2], cfg, dt))
     elif cfg.family == "hybrid":
         every = cfg.shared_attn_every
         n_groups = cfg.n_layers // every
@@ -99,6 +108,78 @@ def init_params(key, cfg: ModelConfig) -> dict:
     else:
         raise ValueError(cfg.family)
     return p
+
+
+def pattern_layout(cfg: ModelConfig):
+    """(prefix, period, n_periods, suffix) of a conv_moe config: layer
+    indices of the unstacked leading and trailing layers, and the period's
+    operator kinds."""
+    n_dense, pat = cfg.n_dense_layers, cfg.layer_pattern
+    per, n_periods = cfg.layer_period()
+    first_suffix = n_dense + per * n_periods
+    return (list(range(n_dense)), tuple(pat[n_dense:n_dense + per]),
+            n_periods, list(range(first_suffix, cfg.n_layers)))
+
+
+def _pattern_layer(key, cfg: ModelConfig, dt, kind: str, dense: bool):
+    k1, k2 = jax.random.split(key)
+    blk = {"ln1": jnp.zeros((cfg.d_model,), dt),
+           "ln2": jnp.zeros((cfg.d_model,), dt)}
+    if kind == "attn":
+        blk["attn"] = L.init_attention(k1, cfg, dt)
+    else:
+        blk["conv"] = L.init_short_conv(k1, cfg, dt)
+    if dense:
+        blk["mlp"] = L.init_mlp(k2, cfg, dt)
+    else:
+        blk["moe"] = MOE.init_moe(k2, cfg, dt)
+    return blk
+
+
+def _init_pattern(key, cfg: ModelConfig, dt) -> dict:
+    prefix, period, n_periods, suffix = pattern_layout(cfg)
+    keys = jax.random.split(key, cfg.n_layers)
+    pat, n_dense = cfg.layer_pattern, cfg.n_dense_layers
+    out: dict = {}
+    if prefix:
+        out["prefix"] = [_pattern_layer(keys[i], cfg, dt, pat[i], True)
+                         for i in prefix]
+    if n_periods:
+        out["period"] = [
+            _stack_init(lambda k, kind=kind: _pattern_layer(
+                k, cfg, dt, kind, False), keys[n_dense + j], n_periods)
+            for j, kind in enumerate(period)]
+    if suffix:
+        out["suffix"] = [_pattern_layer(keys[i], cfg, dt, pat[i], False)
+                         for i in suffix]
+    return out
+
+
+def _pattern_axes(cfg: ModelConfig) -> dict:
+    prefix, period, n_periods, suffix = pattern_layout(cfg)
+
+    def one(kind, dense):
+        blk = {"ln1": (None,), "ln2": (None,)}
+        if kind == "attn":
+            blk["attn"] = {k: v for k, v in L.attention_param_axes().items()
+                           if cfg.qk_norm or k not in ("q_norm", "k_norm")}
+        else:
+            blk["conv"] = L.short_conv_param_axes()
+        blk["mlp" if dense else "moe"] = (L.mlp_param_axes() if dense
+                                          else MOE.moe_param_axes(cfg))
+        return blk
+    stack = lambda d: jax.tree.map(                         # noqa: E731
+        lambda ax: ("p_layers",) + tuple(ax), d,
+        is_leaf=lambda x: isinstance(x, tuple))
+    pat = cfg.layer_pattern
+    out: dict = {}
+    if prefix:
+        out["prefix"] = [one(pat[i], True) for i in prefix]
+    if n_periods:
+        out["period"] = [stack(one(kind, False)) for kind in period]
+    if suffix:
+        out["suffix"] = [one(pat[i], False) for i in suffix]
+    return out
 
 
 def param_axes(cfg: ModelConfig) -> dict:
@@ -124,6 +205,8 @@ def param_axes(cfg: ModelConfig) -> dict:
         axes["layers"] = stack({**blk_attn, "moe": MOE.moe_param_axes(cfg)})
     elif cfg.family == "ssm":
         axes["layers"] = stack({"ln": (None,), "mamba": M.mamba2_param_axes()})
+    elif cfg.family == "conv_moe":
+        axes.update(_pattern_axes(cfg))
     elif cfg.family == "hybrid":
         axes["groups"] = stack({"ln": (None,),
                                 "mamba": M.mamba2_param_axes()}, extra=2)
@@ -175,6 +258,22 @@ def _attn_block(blk, h, cfg, positions, cache=None):
     return h + y, new_cache, aux
 
 
+def _pattern_block(blk, h, cfg, positions):
+    """One conv_moe layer; its kind is read from its weights."""
+    x = L.rmsnorm(h, blk["ln1"], cfg.rmsnorm_eps)
+    if "attn" in blk:
+        a, _ = L.attention(blk["attn"], x, cfg, positions)
+    else:
+        a = L.short_conv(blk["conv"], x, cfg)
+    h = h + a
+    x = L.rmsnorm(h, blk["ln2"], cfg.rmsnorm_eps)
+    if "moe" in blk:
+        y, aux = MOE.moe_layer(blk["moe"], x, cfg)
+    else:
+        y, aux = L.mlp(blk["mlp"], x, cfg), {}
+    return h + y, aux
+
+
 def _mamba_block(blk, h, cfg, state=None):
     y, new_state = M.mamba2_layer(blk["mamba"],
                                   L.rmsnorm(h, blk["ln"], cfg.rmsnorm_eps),
@@ -188,6 +287,8 @@ def _mamba_block(blk, h, cfg, state=None):
 
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int) -> dict:
     """Decode caches, zero-initialized (filled by prefill)."""
+    if cfg.family == "conv_moe":
+        raise NotImplementedError("conv_moe has no serving cache")
     dt = _dtype(cfg)
 
     def kv(n):
@@ -231,6 +332,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int) -> dict:
 def cache_axes(cfg: ModelConfig) -> dict:
     """Logical sharding for caches: KV sequence shards over `model` (SP
     flash-decode), batch over data axes; SSM heads over `model`."""
+    if cfg.family == "conv_moe":
+        raise NotImplementedError("conv_moe has no serving cache")
     kv = {"k": (None, "batch", "seq_sp", None, None),
           "v": (None, "batch", "seq_sp", None, None),
           "length": (None,)}
@@ -270,9 +373,45 @@ def forward(params: dict, inputs: jax.Array, cfg: ModelConfig,
     (B,) for ragged right-padded serving prefill (each row's last real token
     sits at a different offset), or (B, P) to read logits at several
     positions per row (speculative verify reads every draft position)."""
+    logits, new_cache, _aux = _forward(params, inputs, cfg, cache, positions,
+                                       return_cache, logits_mode,
+                                       logits_index)
+    return logits, new_cache
+
+
+def forward_with_aux(params: dict, inputs: jax.Array, cfg: ModelConfig):
+    """The training forward: (logits, aux), where ``aux`` holds the expert
+    layers' counters over the whole model (:func:`_reduce_aux`; empty for a
+    model without experts)."""
+    logits, _, aux = _forward(params, inputs, cfg)
+    return logits, aux
+
+
+def _reduce_aux(per_layer: list) -> dict:
+    """Expert counters of every expert layer, each with a leading layer
+    axis, to the model's: assignments per held expert summed over the
+    layers, and the held share of all assignments."""
+    per_layer = [a for a in per_layer if a]
+    if not per_layer:
+        return {}
+    return {"expert_counts": sum(a["expert_counts"].sum(0)
+                                 for a in per_layer),
+            "held_share": jnp.concatenate(
+                [a["held_share"] for a in per_layer]).mean()}
+
+
+def _one_layer(aux: dict) -> dict:
+    return jax.tree.map(lambda a: a[None], aux)
+
+
+def _forward(params, inputs, cfg, cache=None, positions=None,
+             return_cache=False, logits_mode="all", logits_index=None):
     dt = _dtype(cfg)
     if inputs.ndim == 2 and cfg.frontend == "none":
-        h = params["embed"].astype(dt)[inputs]
+        # rows gathered from the master table, then cast: the gradient
+        # sums a token's uses into its row in float32 (a bf16 scatter-add
+        # loses the small terms of a frequent token's many uses)
+        h = params["embed"][inputs].astype(dt)
         op_hook("embed.lookup", (inputs, params["embed"]), (h,))
     else:
         h = inputs.astype(dt)
@@ -285,9 +424,17 @@ def forward(params: dict, inputs: jax.Array, cfg: ModelConfig,
         else:
             positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
 
+    aux: dict = {}
     if cfg.family in ("dense", "vlm", "audio", "moe"):
-        h, new_cache = _run_stacked_attn(params, h, cfg, positions, cache,
-                                         return_cache)
+        h, new_cache, aux = _run_stacked_attn(params, h, cfg, positions,
+                                              cache, return_cache)
+    elif cfg.family == "conv_moe":
+        if cache is not None or return_cache:
+            raise NotImplementedError(
+                "conv_moe runs training forwards only: the short "
+                "convolution's state has no serving cache")
+        h, aux = _run_pattern(params, h, cfg, positions)
+        new_cache = None
     elif cfg.family == "ssm":
         h, new_cache = _run_stacked_ssm(params, h, cfg, cache, return_cache)
     else:
@@ -310,7 +457,7 @@ def forward(params: dict, inputs: jax.Array, cfg: ModelConfig,
         logits = h
     logits = shard(logits, "batch", "seq", "vocab")
     op_hook("lm_head", (h,), (logits,))
-    return logits, new_cache
+    return logits, new_cache, aux
 
 
 def _cache_length(cache: dict, cfg: ModelConfig):
@@ -324,18 +471,20 @@ def _run_stacked_attn(params, h, cfg, positions, cache, return_cache=False):
     layers = params["layers"]
     if cache is None and not return_cache and _instrumented_eager(h):
         n = jax.tree.leaves(layers)[0].shape[0]
+        auxes = []
         for i in range(n):
             op_hook(f"layer{i}", (h,), ())
-            h, _kv, _aux = _attn_block(_tree_at(layers, i), h, cfg,
-                                       positions, None)
-        return h, None
+            h, _kv, aux = _attn_block(_tree_at(layers, i), h, cfg,
+                                      positions, None)
+            auxes.append(_one_layer(aux))
+        return h, None, _reduce_aux(auxes)
 
     def body(carry, xs):
         hh = carry
         if cache is None:
             blk = xs
-            hh2, kv, _aux = _attn_block(blk, hh, cfg, positions, None)
-            ys = {"k": kv["k"], "v": kv["v"]} if return_cache else None
+            hh2, kv, aux = _attn_block(blk, hh, cfg, positions, None)
+            ys = {"k": kv["k"], "v": kv["v"]} if return_cache else aux
             return hh2, ys
         blk, kv_slice = xs
         hh2, new_kv, _aux = _attn_block(blk, hh, cfg, positions, kv_slice)
@@ -349,11 +498,12 @@ def _run_stacked_attn(params, h, cfg, positions, cache, return_cache=False):
 
     body = _remat(cfg, body)
     if cache is None:
-        h, kv = jax.lax.scan(body, h, layers)
+        h, ys = jax.lax.scan(body, h, layers)
         if not return_cache:
-            return h, None
+            return h, None, _reduce_aux([ys])
         length = jnp.full((h.shape[0],), h.shape[1], jnp.int32)
-        return h, {"kv": {"k": kv["k"], "v": kv["v"], "length": length}}
+        return h, {"kv": {"k": ys["k"], "v": ys["v"],
+                          "length": length}}, {}
     kv = cache["kv"]
     if "pk" in kv:
         n_layers = kv["pk"].shape[0]
@@ -368,7 +518,7 @@ def _run_stacked_attn(params, h, cfg, positions, cache, return_cache=False):
         h, new_kv = jax.lax.scan(body, h, (layers, per_layer))
         return h, {"kv": {"pk": new_kv["pk"], "pv": new_kv["pv"],
                           "bt": kv["bt"],
-                          "length": kv["length"] + h.shape[1]}}
+                          "length": kv["length"] + h.shape[1]}}, {}
     per_layer = {"k": kv["k"], "v": kv["v"], "length": bcast(kv["length"])}
     if "rk" in kv:
         per_layer.update({"rk": kv["rk"], "rv": kv["rv"],
@@ -382,7 +532,50 @@ def _run_stacked_attn(params, h, cfg, positions, cache, return_cache=False):
     else:
         new_cache = {"kv": {"k": new_kv["k"], "v": new_kv["v"],
                             "length": kv["length"] + h.shape[1]}}
-    return h, new_cache
+    return h, new_cache, {}
+
+
+def _run_pattern(params, h, cfg, positions):
+    """conv_moe layers: the unstacked prefix, a scan over whole periods (each
+    layer checkpointed under ``remat``), the unstacked suffix.  Returns (h,
+    the expert counters over every expert layer)."""
+    layer = _remat(cfg, lambda blk, hh: _pattern_block(blk, hh, cfg,
+                                                       positions))
+    if _instrumented_eager(h):
+        auxes = []
+        for i, blk in enumerate(_pattern_layers(params)):
+            op_hook(f"layer{i}", (h,), ())
+            h, aux = _pattern_block(blk, h, cfg, positions)
+            auxes.append(_one_layer(aux))
+        return h, _reduce_aux(auxes)
+
+    auxes = []
+    for blk in params.get("prefix", []):
+        h, aux = layer(blk, h)
+        auxes.append(_one_layer(aux))
+    if "period" in params:
+        def body(hh, blks):
+            ys = []
+            for blk in blks:
+                hh, aux = layer(blk, hh)
+                ys.append(aux)
+            return hh, ys
+        h, ys = jax.lax.scan(body, h, params["period"])
+        auxes += ys
+    for blk in params.get("suffix", []):
+        h, aux = layer(blk, h)
+        auxes.append(_one_layer(aux))
+    return h, _reduce_aux(auxes)
+
+
+def _pattern_layers(params) -> list:
+    """Every conv_moe layer's weights, in order."""
+    out = list(params.get("prefix", []))
+    if "period" in params:
+        n = jax.tree.leaves(params["period"])[0].shape[0]
+        out += [_tree_at(blk, i) for i in range(n)
+                for blk in params["period"]]
+    return out + list(params.get("suffix", []))
 
 
 def _run_stacked_ssm(params, h, cfg, cache, return_cache=False):

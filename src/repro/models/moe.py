@@ -1,22 +1,36 @@
-"""Mixture-of-Experts layer: sort-based capacity dispatch + block-diagonal
-expert matmuls.
+"""Mixture-of-Experts layer: a router over all experts and a dropless
+expert share computed as grouped matmuls.
 
-Dispatch avoids the classic (tokens × experts × capacity) one-hot einsum —
-whose FLOPs would swamp the real compute at 384 experts — in favour of a
-sort/scatter pipeline whose arithmetic cost is negligible and whose expert
-matmuls cost exactly ``2 · E · C · d · f`` = active-FLOPs × capacity factor:
+  1. router (float32): ``softmax(x Wg)``, or ``sigmoid(x Wg)`` with the
+     experts chosen by ``top-k(sigmoid + b)`` for an expert bias ``b``
+     (``router="sigmoid_bias"``); the gates are the chosen scores,
+     renormalized over the k chosen;
+  2. the layer holds experts ``[0, held)`` (``n_experts_held``; all by
+     default).  The (T·k) token-expert assignments are sorted by expert, so
+     those of held experts form one contiguous ragged run of groups;
+  3. the held experts' SwiGLU runs as three grouped matmuls
+     (``jax.lax.ragged_dot``) over those groups: each computes exactly the
+     rows routed to it, and no assignment is dropped;
+  4. the rows are gathered back into token order and summed with their
+     gates.  An assignment to an expert held elsewhere contributes nothing
+     here: on one chip of an expert-parallel group that is this chip's part
+     of the layer's result.
 
-  1. router: softmax(x @ Wg) → top-k experts + gates per token;
-  2. stable argsort of the (T·k) expert assignments → contiguous groups;
-  3. rank-in-group via group starts (searchsorted); tokens past the per-
-     expert capacity C are dropped (standard capacity semantics);
-  4. scatter token rows into the (E, C, d) buffer; two batched einsums
-     (SwiGLU) over the expert dim; gather back; gate-weighted sum over k.
+Rows past the held groups are masked before and after every grouped matmul,
+so whatever the matmul leaves there reaches neither the result nor a
+gradient.  The selection takes no gradient, so the expert bias gets none.
 
-Parallelism: expert *hidden* dim shards over the ``model`` axis (TP-MoE —
-routing stays local, no all-to-all; the classic EP all-to-all variant is a
-perf-loop alternative), experts' leading dim shards over ``data`` for ZeRO-3.
-Shared experts (Kimi-style) run densely for every token.
+Counters (the layer's ``aux``): ``expert_counts``, the assignments each held
+expert computed (int32 [held]), and ``held_share``, the share of all
+assignments that went to held experts.
+
+Parallelism (:func:`sharded_experts`): the expert weights shard by
+``moe_param_axes``, the hidden dim over ``model`` (TP), or the experts over
+``model`` with ``moe_parallelism="ep"``, each device then holding a
+contiguous share of them.  Each data shard sorts and computes its own
+tokens' assignments; a device's output is a partial sum over ``model``,
+which one all-reduce completes.  Shared experts (Kimi-style) run densely
+for every token.
 """
 
 from __future__ import annotations
@@ -26,21 +40,26 @@ import math
 import jax
 import jax.numpy as jnp
 
+from jax.sharding import PartitionSpec as P
+
 from repro.core.instrument import op_hook
-from repro.dist.sharding import shard
+from repro.dist.sharding import get_mesh, logical, shard
 from .config import ModelConfig
 
 
 def init_moe(key, cfg: ModelConfig, dtype) -> dict:
-    d, f, e = cfg.d_model, cfg.d_ff_expert, cfg.n_experts
+    d, f, e, h = cfg.d_model, cfg.d_ff_expert, cfg.n_experts, \
+        cfg.experts_held
     ks = jax.random.split(key, 5)
     s_in, s_out = 1.0 / math.sqrt(d), 1.0 / math.sqrt(f)
     p = {
         "router": jax.random.normal(ks[0], (d, e), jnp.float32) * s_in,
-        "w_gate": jax.random.normal(ks[1], (e, d, f), dtype) * s_in,
-        "w_up": jax.random.normal(ks[2], (e, d, f), dtype) * s_in,
-        "w_down": jax.random.normal(ks[3], (e, f, d), dtype) * s_out,
+        "w_gate": jax.random.normal(ks[1], (h, d, f), dtype) * s_in,
+        "w_up": jax.random.normal(ks[2], (h, d, f), dtype) * s_in,
+        "w_down": jax.random.normal(ks[3], (h, f, d), dtype) * s_out,
     }
+    if cfg.router == "sigmoid_bias":
+        p["bias"] = jnp.zeros((e,), jnp.float32)
     if cfg.n_shared_experts:
         fs = f * cfg.n_shared_experts
         k1, k2, k3 = jax.random.split(ks[4], 3)
@@ -66,6 +85,8 @@ def moe_param_axes(cfg: ModelConfig) -> dict:
             "w_up": ("p_experts", "p_embed", "p_expert_ff"),
             "w_down": ("p_experts", "p_expert_ff", "p_embed"),
         }
+    if cfg.router == "sigmoid_bias":
+        axes["bias"] = (None,)
     if cfg.n_shared_experts:
         axes.update({"ws_gate": ("p_embed", "p_expert_ff"),
                      "ws_up": ("p_embed", "p_expert_ff"),
@@ -73,103 +94,134 @@ def moe_param_axes(cfg: ModelConfig) -> dict:
     return axes
 
 
-def _dispatch_group(xt, probs, k: int, e: int, cap: int, dt):
-    """Sort-based capacity dispatch for ONE token group (vmapped over the
-    data-parallel group dim so routing never crosses shards)."""
-    t = xt.shape[0]
-    gates, topk = jax.lax.top_k(probs, k)                  # (t,k)
+def route(p: dict, xt: jax.Array, cfg: ModelConfig):
+    """(gates (T,k) f32, experts (T,k) int32) of tokens ``xt`` (T,d)."""
+    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), p["router"],
+                        precision=jax.lax.Precision.HIGHEST)
+    if cfg.router == "sigmoid_bias":
+        scores = jax.nn.sigmoid(logits)
+        _, experts = jax.lax.top_k(scores + p["bias"], cfg.n_experts_active)
+        gates = jnp.take_along_axis(scores, experts, axis=-1)
+    elif cfg.router == "softmax":
+        gates, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                       cfg.n_experts_active)
+    else:
+        raise ValueError(f"unknown router {cfg.router!r}")
     gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-    flat_e = topk.reshape(t * k)
-    order = jnp.argsort(flat_e, stable=True)               # (t·k,)
-    sorted_e = flat_e[order]
-    starts = jnp.searchsorted(sorted_e, jnp.arange(e, dtype=sorted_e.dtype))
-    rank = jnp.arange(t * k, dtype=jnp.int32) - starts[sorted_e]
-    keep = rank < cap
-    slot = sorted_e.astype(jnp.int32) * cap + jnp.clip(rank, 0, cap - 1)
-    slot = jnp.where(keep, slot, e * cap)                  # overflow row
-    src = order // k                                       # source token copy
-    d = xt.shape[-1]
-    xe = jnp.zeros((e * cap + 1, d), dt).at[slot].set(
-        xt[src], mode="drop", unique_indices=False)
-    return xe[:e * cap].reshape(e, cap, d), (gates, order, slot, keep)
+    return gates, experts.astype(jnp.int32)
 
 
-def _combine_group(ye, gates, order, slot, keep, k: int, dt):
-    e, cap, d = ye.shape
-    t = gates.shape[0]
-    ye_flat = jnp.concatenate([ye.reshape(e * cap, d),
-                               jnp.zeros((1, d), dt)], axis=0)
-    y_copies = jnp.where(keep[:, None], ye_flat[slot], 0)  # (t·k, d)
-    y_sorted = jnp.zeros((t * k, d), dt).at[order].set(y_copies)
-    return (y_sorted.reshape(t, k, d) * gates.astype(dt)[..., None]).sum(1)
+def held_experts(p: dict, xt: jax.Array, gates, experts, lo=0):
+    """The part of the layer of experts ``[lo, lo + held)`` for tokens ``xt``
+    (T,d): (y (T,d), counts int32 [held], held assignments)."""
+    dt = xt.dtype
+    t, k = experts.shape
+    held = p["w_gate"].shape[0]
+    flat = experts.reshape(t * k) - lo
+    # assignments of experts held elsewhere sort last, into a group of
+    # their own that no grouped matmul visits
+    key = jnp.where((flat >= 0) & (flat < held), flat, held)
+    order = jnp.argsort(key, stable=True)
+    counts = (key[:, None] == jnp.arange(held)[None, :]).sum(
+        0, dtype=jnp.int32)
+    n_held = counts.sum()
+    live = (jnp.arange(t * k) < n_held)[:, None]
+
+    def grouped(lhs, w):
+        out = jax.lax.ragged_dot(jnp.where(live, lhs, 0), w.astype(dt),
+                                 counts)
+        return jnp.where(live, out, 0)
+
+    # permutations only, so that every gather's transpose is a scatter of
+    # unique rows
+    xs = jnp.repeat(xt, k, axis=0).at[order].get(unique_indices=True)
+    g = grouped(xs, p["w_gate"])
+    u = grouped(xs, p["w_up"])
+    ys = grouped(jax.nn.silu(g) * u, p["w_down"])
+    # back to (token, slot) order; a slot whose expert is not held reads 0
+    inv = jnp.zeros_like(order).at[order].set(
+        jnp.arange(t * k, dtype=order.dtype), unique_indices=True)
+    y = ys.at[inv].get(unique_indices=True).reshape(t, k, -1)
+    y = (y * gates.astype(dt)[..., None]).sum(axis=1)
+    return y, counts, n_held
+
+
+def _axis(spec):
+    return spec[0] if len(spec) else None
+
+
+def sharded_experts(w: dict, xt: jax.Array, gates, experts,
+                    cfg: ModelConfig):
+    """:func:`held_experts` over the registered mesh, in one ``shard_map``:
+    each data shard sorts and computes its own tokens' assignments, so no
+    row leaves its shard.  Over ``model``, TP splits every expert's hidden
+    dim and EP (``moe_parallelism="ep"``) gives each device a contiguous
+    share of the experts; either way a device's output is a partial sum
+    that one ``psum`` over ``model`` completes.  Without a mesh of more than
+    one device it is :func:`held_experts` itself."""
+    mesh = get_mesh()
+    if mesh is None or mesh.size == 1:
+        return held_experts(w, xt, gates, experts)
+    held = cfg.experts_held
+    tok = _axis(logical("batch", dims=(xt.shape[0],)))
+    ep = cfg.moe_parallelism == "ep"
+    if ep:
+        ax = _axis(logical("p_experts_ep", dims=(held,)))
+        w_specs = {k: P(ax) for k in w}
+    else:
+        ax = _axis(logical("p_expert_ff", dims=(cfg.d_ff_expert,)))
+        w_specs = {"w_gate": P(None, None, ax), "w_up": P(None, None, ax),
+                   "w_down": P(None, ax, None)}
+    if tok is None and ax is None:
+        return held_experts(w, xt, gates, experts)
+    n_ax = mesh.shape[ax] if ax else 1
+
+    def local(w, xt, gates, experts):
+        lo = 0
+        if ep and ax:
+            lo = jax.lax.axis_index(ax) * (held // n_ax)
+            # each device reads the tokens for its own experts: marked as
+            # varying over `model`, so that their gradient sums the shares
+            xt = jax.lax.pcast(xt, ax, to="varying")
+        y, counts, n = held_experts(w, xt, gates, experts, lo)
+        if ax:
+            y = jax.lax.psum(y, ax)
+        if tok:
+            counts, n = jax.lax.psum((counts, n), tok)
+        if ep and ax:
+            n = jax.lax.psum(n, ax)
+        return y, counts, n
+
+    t_spec = P(tok)
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(w_specs, t_spec, t_spec, t_spec),
+        out_specs=(t_spec, P(ax) if ep else P(), P()))(
+            w, xt, gates, experts)
 
 
 def moe_layer(p: dict, x: jax.Array, cfg: ModelConfig):
-    """x: (B,S,d). Returns (y, aux) with load-balancing stats.
-
-    Tokens are reshaped to (G, t/G, d) with G = the data-parallel degree and
-    the group dim sharded over it, so dispatch (argsort/scatter) is local to
-    each data shard and expert matmuls carry exactly the active FLOPs ×
-    capacity factor per device.  Expert hidden dim shards over ``model``
-    (TP-MoE: no all-to-all; the EP all-to-all variant is a perf-loop
-    alternative — see repro.dist).
-    """
-    from repro.dist.sharding import mesh_axis_size
+    """x: (B,S,d). Returns (y, aux) with the expert counters."""
     dt = x.dtype
     b, s, d = x.shape
-    e, k = cfg.n_experts, cfg.n_experts_active
-    t = b * s
-    g = mesh_axis_size("pod") * mesh_axis_size("data")
-    while g > 1 and t % g:
-        g //= 2
-    tl = t // g
-    xt = x.reshape(g, tl, d)
-    xt = shard(xt, "batch", None, "embed")
-
-    # ---- router (f32) -----------------------------------------------------
-    logits = jnp.einsum("gtd,de->gte", xt.astype(jnp.float32), p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-
-    cap = int(math.ceil(tl * k / e * cfg.capacity_factor))
-    cap = max(4, min(cap, tl))
-    xe, meta = jax.vmap(
-        lambda xg, pg: _dispatch_group(xg, pg, k, e, cap, dt))(xt, probs)
-    ep = cfg.moe_parallelism == "ep"
-    e_ax = "experts_ep" if ep else "experts"
-    f_ax = None if ep else "expert_ff"
-    # EP: this constraint is the token all-to-all (capacity rows redistribute
-    # from data-sharded groups to expert-sharded devices); TP: replicated
-    # expert dim, hidden dim sharded — no token movement.
-    xe = shard(xe, "batch", e_ax, None, "embed")           # (g,e,cap,d)
-
-    # ---- expert SwiGLU (block-diagonal over experts) ------------------------
-    gt = jnp.einsum("gecd,edf->gecf", xe, p["w_gate"].astype(dt))
-    u = jnp.einsum("gecd,edf->gecf", xe, p["w_up"].astype(dt))
-    gt = shard(gt, "batch", e_ax, None, f_ax)
-    u = shard(u, "batch", e_ax, None, f_ax)
-    h = jax.nn.silu(gt) * u
-    ye = jnp.einsum("gecf,efd->gecd", h, p["w_down"].astype(dt))
-    ye = shard(ye, "batch", e_ax, None, "embed")
-    op_hook("moe.experts", (xe, p["w_gate"], p["w_up"], p["w_down"]), (ye,))
-
-    y = jax.vmap(lambda yg, m: _combine_group(yg, *m, k, dt))(ye, meta)
+    xt = shard(x.reshape(b * s, d), "batch", "embed")
+    with jax.named_scope("moe.route"):
+        gates, experts = route(p, xt, cfg)
+    with jax.named_scope("moe.experts"):
+        y, counts, n_held = sharded_experts(
+            {k: p[k] for k in ("w_gate", "w_up", "w_down")}, xt, gates,
+            experts, cfg)
+    op_hook("moe.experts", (x, p["w_gate"], p["w_up"], p["w_down"]), (y,))
 
     # ---- shared experts (dense) --------------------------------------------
     if cfg.n_shared_experts:
-        sg = jnp.einsum("gtd,df->gtf", xt, p["ws_gate"].astype(dt))
-        su = jnp.einsum("gtd,df->gtf", xt, p["ws_up"].astype(dt))
-        sg = shard(sg, "batch", None, "expert_ff")
-        su = shard(su, "batch", None, "expert_ff")
-        y = y + jnp.einsum("gtf,fd->gtd", jax.nn.silu(sg) * su,
+        sg = jnp.einsum("td,df->tf", xt, p["ws_gate"].astype(dt))
+        su = jnp.einsum("td,df->tf", xt, p["ws_up"].astype(dt))
+        sg = shard(sg, "batch", "expert_ff")
+        su = shard(su, "batch", "expert_ff")
+        y = y + jnp.einsum("tf,fd->td", jax.nn.silu(sg) * su,
                            p["ws_down"].astype(dt))
 
-    # load-balance aux loss (Switch-style)
-    me = probs.mean(axis=(0, 1))                           # (e,)
-    _gates, _order, slot, keep = meta
-    flat_e = jnp.clip(slot // cap, 0, e - 1)
-    ce = jnp.zeros((e,), jnp.float32).at[flat_e.reshape(-1)].add(
-        keep.reshape(-1).astype(jnp.float32)) / (t * k)
-    aux = {"lb_loss": e * jnp.sum(me * ce),
-           "dropped_frac": 1.0 - keep.mean()}
+    aux = {"expert_counts": counts,
+           "held_share": n_held.astype(jnp.float32) / experts.size}
     y = y.reshape(b, s, d)
     return shard(y, "batch", "seq", "embed"), aux

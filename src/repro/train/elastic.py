@@ -102,8 +102,11 @@ class TrainLoop:
             self._watchdog(step, dur)
             self.handler.step_end(step, loss=loss, duration_s=dur)
             if metrics_cb:
+                # scalars only: a model with experts adds per-expert
+                # counters (``expert_counts``) to the step's metrics
                 metrics_cb(step, {k: float(np.asarray(v))
-                                  for k, v in metrics.items()})
+                                  for k, v in metrics.items()
+                                  if np.ndim(v) == 0})
             step += 1
             if self.cfg.ckpt_dir and step % self.cfg.ckpt_every == 0:
                 ckpt.save(self.cfg.ckpt_dir, step,

@@ -6,7 +6,9 @@
 
 with microbatched gradient accumulation (``lax.scan`` over microbatches —
 one psum per accumulation window, the standard compute/comm-overlap layout),
-global-norm clipping and AdamW.  Sharding trees are produced from the model's
+global-norm clipping and AdamW.  For a model with experts, ``metrics``
+carry the step's expert counters (``expert_counts``, ``held_share``;
+:mod:`repro.models.moe`).  Sharding trees are produced from the model's
 logical param axes via :mod:`repro.dist.sharding`.
 
 Cross-pod gradient sync (``overlap_sync=``):
@@ -46,8 +48,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.dist.collectives import make_pod_sync, psum_start, psum_wait
 from repro.dist.sharding import logical, set_mesh
-from repro.models import (forward, cross_entropy, init_params, param_axes,
-                          init_cache, cache_axes)
+from repro.models import (forward, forward_with_aux, cross_entropy,
+                          init_params, param_axes, init_cache, cache_axes)
 from repro.models.config import ModelConfig
 from .optimizer import OptConfig, init_opt_state, opt_state_axes, adamw_update
 
@@ -267,9 +269,9 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptConfig,
                     microbatches: int = 1, overlap_sync: bool | None = None,
                     sync_compressed: bool = False, sync_buckets: int = 4):
     def loss_fn(params, inputs, labels):
-        logits, _ = forward(params, inputs, cfg)
-        loss, parts = cross_entropy(logits, labels)
-        return loss, parts
+        logits, aux = forward_with_aux(params, inputs, cfg)
+        loss, _parts = cross_entropy(logits, labels)
+        return loss, aux
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
@@ -292,7 +294,7 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptConfig,
         if cfg.gather_params_once and microbatches > 1:
             params = _gather_once(params, cfg)
         if microbatches == 1:
-            (loss, _parts), grads = grad_fn(params, inputs, labels)
+            (loss, aux), grads = grad_fn(params, inputs, labels)
         else:
             m = microbatches
             b = inputs.shape[0]
@@ -302,21 +304,23 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptConfig,
 
             def micro(carry, mb):
                 acc, lsum = carry
-                (l, _p), g = grad_fn(params, mb["i"], mb["l"])
+                (l, a), g = grad_fn(params, mb["i"], mb["l"])
                 acc = jax.tree.map(jnp.add, acc, g)
-                return (acc, lsum + l), None
+                return (acc, lsum + l), a
 
             zeros = jax.tree.map(jnp.zeros_like, params)
-            (grads, lsum), _ = jax.lax.scan(
+            (grads, lsum), auxes = jax.lax.scan(
                 micro, (zeros, jnp.zeros((), jnp.float32)),
                 {"i": mb_in, "l": mb_lb})
             grads = jax.tree.map(lambda g: g / m, grads)
             loss = lsum / m
+            aux = {k: v.sum(0) if k == "expert_counts" else v.mean(0)
+                   for k, v in auxes.items()}
         grads, grad_sqnorm = pod_sync(grads)
         new_params, new_opt, om = adamw_update(params, grads, opt_state,
                                                opt_cfg,
                                                grad_sqnorm=grad_sqnorm)
-        metrics = {"loss": loss, **om,
+        metrics = {"loss": loss, **om, **aux,
                    "tokens": jnp.asarray(inputs.shape[0] * inputs.shape[1],
                                          jnp.float32)}
         return new_params, new_opt, metrics

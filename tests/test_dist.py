@@ -292,3 +292,60 @@ def test_production_mesh_shapes():
         print("OK")
     """, devices=512)
     assert "OK" in out
+
+
+@pytest.mark.parametrize("arch,held", [("dbrx-132b", 0),
+                                       ("kimi-k2-1t-a32b", 0),
+                                       ("lfm2-8b-a1b", 4)])
+def test_sharded_expert_layer_matches_one_device(arch, held):
+    """On a 2x2 data x model mesh, TP and EP expert layers (each data shard
+    routing its own tokens, one all-reduce over ``model``) give one
+    device's loss, gradient and expert counters; with a held share, EP
+    splits the held experts over ``model``."""
+    out = run_sub(f"""
+        import dataclasses
+        import jax, numpy as np
+        from jax.sharding import NamedSharding
+        import repro.configs as C
+        from repro.dist.sharding import logical, set_mesh
+        from repro.models import (cross_entropy, forward_with_aux,
+                                  init_params, param_axes)
+
+        base = dataclasses.replace(C.reduced(C.get("{arch}")),
+                                   n_experts_held={held})
+        params = init_params(jax.random.PRNGKey(0), base)
+        x = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0,
+                               base.vocab_size)
+
+        def run(cfg, mesh):
+            set_mesh(mesh)
+            def loss(p):
+                logits, aux = forward_with_aux(p, x, cfg)
+                return cross_entropy(logits, x)[0], aux
+            p = params
+            if mesh is not None:
+                is_ax = lambda a: isinstance(a, tuple) and all(
+                    isinstance(e, (str, type(None))) for e in a)
+                p = jax.device_put(params, jax.tree.map(
+                    lambda a, v: NamedSharding(
+                        mesh, logical(*a, dims=v.shape)),
+                    param_axes(cfg), params, is_leaf=is_ax))
+            (l, aux), g = jax.jit(jax.value_and_grad(loss, has_aux=True))(p)
+            set_mesh(None)
+            return float(l), jax.device_get(aux), jax.device_get(g)
+
+        l0, a0, g0 = run(base, None)
+        mesh = make_mesh((2, 2), ("data", "model"))
+        for par in ("tp", "ep"):
+            l, a, g = run(dataclasses.replace(base, moe_parallelism=par),
+                          mesh)
+            assert abs(l - l0) < 1e-5, (par, l, l0)
+            np.testing.assert_array_equal(a["expert_counts"],
+                                          a0["expert_counts"])
+            assert abs(float(a["held_share"])
+                       - float(a0["held_share"])) < 1e-6
+            for u, v in zip(jax.tree.leaves(g), jax.tree.leaves(g0)):
+                np.testing.assert_allclose(u, v, atol=2e-5, rtol=1e-4)
+            print(par, "ok", a["expert_counts"].shape, float(a["held_share"]))
+    """, devices=4)
+    assert out.count(" ok ") == 2, out

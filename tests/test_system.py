@@ -242,3 +242,22 @@ def test_train_driver_cli_resume(tmp_path):
     assert r2.returncode == 0, r2.stderr
     assert "resumed from step 6" in r2.stdout
     assert "done at step 8" in r2.stdout
+
+
+@pytest.mark.parametrize("arch", ["dbrx-132b", "lfm2-8b-a1b"])
+def test_train_driver_cli_runs_expert_models(arch):
+    """CLI driver on a model with experts: the step's metrics carry the
+    per-expert counters beside the scalars, and the loop's callback still
+    prints every step."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    args = [sys.executable, "-m", "repro.launch.train", "--arch", arch,
+            "--reduced", "--steps", "2", "--seq-len", "32",
+            "--global-batch", "2", "--pasta-tools", "kernel_freq"]
+    r = subprocess.run(args, capture_output=True, text=True, env=env,
+                       timeout=600)
+    assert r.returncode == 0, r.stderr
+    steps = [ln for ln in r.stdout.splitlines()
+             if ln.startswith("[train] step")]
+    assert len(steps) == 2, r.stdout
+    assert "done at step 2" in r.stdout

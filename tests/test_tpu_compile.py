@@ -7,6 +7,7 @@ The topology is described only inside the fixture: one process at a time
 may load libtpu, and a test worker that is not given this file never does.
 """
 
+import dataclasses
 import functools
 import os
 
@@ -21,6 +22,7 @@ from repro.kernels.instrumented_matmul import matmul_traced_pallas
 from repro.kernels.trace_aggregate import (object_histogram_pallas,
                                            trace_aggregate_pallas)
 from repro.models import init_params
+from repro.models.moe import init_moe, moe_layer
 from repro.serve.engine import ServeEngine
 
 N = 2 ** 20                 # trace records per call
@@ -28,6 +30,7 @@ K = 1024                    # objects, tiled kernel
 #: 2 MiB blocks covering stablelm-1.6b's f32 params, padded to the tile
 HOT_BLOCKS = -(-3328 // BLOCK_B) * BLOCK_B
 FUSED = dict(k=256, n_blocks=512, n_tbins=8)   # a size can_fuse accepts
+EXPERTS = dataclasses.replace(C.get("lfm2-8b-a1b"), n_experts_held=8)
 
 
 @pytest.fixture(scope="module")
@@ -78,11 +81,21 @@ def _kernel_cases(s):
         "matmul_traced": (
             matmul_traced_pallas,
             (s((1024, 1024), jnp.bfloat16), s((1024, 1024), jnp.bfloat16))),
+        # lfm2-8b-a1b's expert layer on one chip's 8 of 32 experts, forward
+        # and backward: its grouped matmuls lower to Mosaic kernels
+        "expert_share": (
+            jax.grad(lambda x, w: moe_layer(w, x, EXPERTS)[0].astype(
+                jnp.float32).sum(), argnums=(0, 1)),
+            (s((2, 1024, EXPERTS.d_model), jnp.bfloat16),
+             jax.tree.map(lambda a: s(a.shape, a.dtype), jax.eval_shape(
+                 lambda: init_moe(jax.random.PRNGKey(0), EXPERTS,
+                                  jnp.float32))))),
     }
 
 
 @pytest.mark.parametrize("name", ["object_histogram", "hotness_histogram",
-                                  "trace_aggregate", "matmul_traced"])
+                                  "trace_aggregate", "matmul_traced",
+                                  "expert_share"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, args = _kernel_cases(functools.partial(_spec, sharding=one_chip))[name]
     compiled = jax.jit(fn).lower(*args).compile()
